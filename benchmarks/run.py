@@ -14,6 +14,7 @@ import traceback
 from benchmarks import (fig7_inference_time, fig8_framework, fig11_dxenos,
                         roofline, serving_throughput, table2_auto_time,
                         table4_operators)
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = {
     "fig7": fig7_inference_time.run,
@@ -44,4 +45,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

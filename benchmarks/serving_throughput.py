@@ -72,9 +72,6 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import os
-import subprocess
-import sys
 import time
 from pathlib import Path
 
@@ -88,7 +85,7 @@ from repro.models.model import Model
 from repro.serving import (ReplicaRouter, Request, SamplingParams,
                            ServingEngine, SpecParams, settle_ticks)
 
-from .common import emit
+from .common import emit, run_on_host_devices
 
 ARCH = "qwen3-1.7b"
 REQUESTS = 8
@@ -281,13 +278,15 @@ def run_spec() -> dict[str, float]:
 #
 # Two orthogonal axes, recorded in ``BENCH_serving.json``:
 #
-#   * **mesh shards** (1/2/4 simulated CPU devices): each shard count runs
-#     in a *subprocess* with ``--xla_force_host_platform_device_count``
-#     (the device count is locked at first backend init).  On one physical
-#     core the forced devices timeshare it, so wall-clock does not improve
-#     — the honest scaling signal reported is the per-shard KV footprint
-#     (bytes/device drop 1/n, which is exactly what concat-TP buys an edge
-#     deployment) plus the measured tok/s for the record;
+#   * **mesh shards** (1/2/4 devices): on the CPU each shard count runs
+#     in a CPU-pinned *subprocess* with
+#     ``--xla_force_host_platform_device_count`` (the device count is
+#     locked at first backend init); on an accelerator it runs in this
+#     process over the real devices, up to as many as there are.  On one
+#     physical core the forced devices timeshare it, so wall-clock does
+#     not improve — the honest scaling signal reported is the per-shard
+#     KV footprint (bytes/device drop 1/n, which is exactly what concat-TP
+#     buys an edge deployment) plus the measured tok/s for the record;
 #   * **replicas** (1/2/4 routed engines): weak scaling — the workload
 #     grows with the fleet so every replica decodes full batches.  The
 #     aggregate is the sum of per-replica busy-time decode rates: the
@@ -301,73 +300,72 @@ SCALE_REQS_PER_REPLICA = 2 * SLOTS   # two full admission waves per replica
 BENCH_JSON = Path(__file__).resolve().parent.parent / "BENCH_serving.json"
 
 #: every dim divisible by 4 shards (kv heads are the binding axis)
-_SHARD_BENCH = r"""
-import json, time
-import jax, numpy as np
-from repro.configs.base import ModelConfig
-from repro.models.model import Model
-from repro.launch.mesh import make_serving_mesh
-from repro.serving import Request, ServingEngine
+SHARD_CFG = ModelConfig(name="scale-tiny", family="dense", n_layers=2,
+                        d_model=128, vocab=96, n_heads=8, n_kv_heads=4,
+                        d_ff=256, dtype="float32", param_dtype="float32")
 
-SHARDS = %(shards)d
-cfg = ModelConfig(name="scale-tiny", family="dense", n_layers=2,
-                  d_model=128, vocab=96, n_heads=8, n_kv_heads=4,
-                  d_ff=256, dtype="float32", param_dtype="float32")
-model = Model(cfg)
-params = model.init(jax.random.key(0))
-mesh = make_serving_mesh(SHARDS) if SHARDS > 1 else None
 
-def serve():
-    eng = ServingEngine(model, params, slots=2, max_len=64, chunk=8,
-                        prefill_mode="chunked", replan_every=10_000,
-                        kv="paged", kv_block_size=8, mesh=mesh)
-    rng = np.random.default_rng(0)
-    reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab, 16)
-                    .astype(np.int32), max_new_tokens=8) for i in range(4)]
-    t0 = time.perf_counter()
-    for r in reqs:
-        eng.submit(r)
-    eng.run()
-    return time.perf_counter() - t0, eng.stats()
+def shard_row(shards: int) -> dict:
+    """One concat-TP serving run over ``shards`` of this process's devices:
+    wall-clock, decode rate and the KV bytes each device holds per block."""
+    from repro.launch.mesh import make_serving_mesh
+    cfg = SHARD_CFG
+    model = Model(cfg)
+    params = model.init(jax.random.key(0))
+    mesh = make_serving_mesh(shards) if shards > 1 else None
 
-serve()                       # compile off the clock
-dt, stats = serve()
-kp = stats["kv_pool"]
-per_block = kp.get("per_shard", {}).get("block_bytes")
-if per_block is None:         # unsharded: dense per-block payload
-    import jax.numpy as jnp
-    per_block = (2 * kp["block_size"] * cfg.n_kv_heads
-                 * cfg.resolved_head_dim
-                 * jnp.dtype(cfg.dtype).itemsize)
-print("SCALE_JSON " + json.dumps({
-    "shards": SHARDS, "devices": len(jax.devices()), "wall_s": dt,
-    "decode_tokens_per_s": stats.get("decode_tokens_per_s", 0.0),
-    "kv_bytes_per_block_per_device": int(per_block)}))
-"""
+    def serve():
+        eng = ServingEngine(model, params, slots=2, max_len=64, chunk=8,
+                            prefill_mode="chunked", replan_every=10_000,
+                            kv="paged", kv_block_size=8, mesh=mesh)
+        rng = np.random.default_rng(0)
+        reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab, 16)
+                        .astype(np.int32), max_new_tokens=8)
+                for i in range(4)]
+        t0 = time.perf_counter()
+        for r in reqs:
+            eng.submit(r)
+        eng.run()
+        return time.perf_counter() - t0, eng.stats()
+
+    serve()                       # compile off the clock
+    dt, stats = serve()
+    kp = stats["kv_pool"]
+    per_block = kp.get("per_shard", {}).get("block_bytes")
+    if per_block is None:         # unsharded: dense per-block payload
+        per_block = (2 * kp["block_size"] * cfg.n_kv_heads
+                     * cfg.resolved_head_dim
+                     * jnp.dtype(cfg.dtype).itemsize)
+    return {"shards": shards, "devices": len(jax.devices()), "wall_s": dt,
+            "decode_tokens_per_s": stats.get("decode_tokens_per_s", 0.0),
+            "kv_bytes_per_block_per_device": int(per_block)}
 
 
 def _bench_shards(shards: int) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={shards}"
-    repo = Path(__file__).resolve().parent.parent
-    env["PYTHONPATH"] = str(repo / "src")
-    out = subprocess.run([sys.executable, "-c",
-                          _SHARD_BENCH % {"shards": shards}],
-                         env=env, capture_output=True, text=True,
-                         timeout=900)
-    assert out.returncode == 0, f"shard bench failed:\n{out.stderr}"
-    line = next(l for l in out.stdout.splitlines()
-                if l.startswith("SCALE_JSON "))
+    """A shard row on the CPU runs in a child with ``shards`` forced host
+    devices (the count is locked at backend init); on an accelerator it
+    runs here, on this process's own devices."""
+    if jax.default_backend() != "cpu":
+        return shard_row(shards)
+    out = run_on_host_devices(
+        "import json\n"
+        "from benchmarks.serving_throughput import shard_row\n"
+        f"print('SCALE_JSON ' + json.dumps(shard_row({shards})))\n",
+        shards)
+    line = next(l for l in out.splitlines() if l.startswith("SCALE_JSON "))
     return json.loads(line[len("SCALE_JSON "):])
 
 
 def _bench_replicas(model, params, cfg, replicas: int) -> dict:
-    def build():
+    devices = jax.devices()
+
+    def build(i):
         return ServingEngine(model, params, slots=SLOTS, max_len=MAX_LEN,
                              chunk=CHUNK, prefill_mode="chunked",
                              replan_every=10_000, kv="paged",
-                             kv_block_size=KV_BLOCK)
-    router = ReplicaRouter([build() for _ in range(replicas)])
+                             kv_block_size=KV_BLOCK,
+                             device=devices[i % len(devices)])
+    router = ReplicaRouter([build(i) for i in range(replicas)])
     rng = np.random.default_rng(0)
     n_req = SCALE_REQS_PER_REPLICA * replicas
     reqs = [Request(rid=i,
@@ -389,6 +387,11 @@ def _bench_replicas(model, params, cfg, replicas: int) -> dict:
 
 
 def run_scaling() -> None:
+    # shard rows first: on the CPU their children start before this
+    # process holds any array
+    shard_rows = [_bench_shards(s) for s in SCALE_SHARDS
+                  if jax.default_backend() == "cpu"
+                  or s <= len(jax.devices())]
     cfg = get_config(ARCH).reduced()
     model = Model(cfg)
     params = model.init(jax.random.key(0))
@@ -396,7 +399,6 @@ def run_scaling() -> None:
 
     replica_rows = [_bench_replicas(model, params, cfg, r)
                     for r in SCALE_REPLICAS]
-    shard_rows = [_bench_shards(s) for s in SCALE_SHARDS]
 
     record = {
         "generated_by": "benchmarks/serving_throughput.py run_scaling",

@@ -11,6 +11,7 @@ import numpy as np
 from repro.core import DeviceSpec, Graph, execute, init_params, pipeline
 from repro.core import dos, patterns, planner
 from repro.core import graph as G
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build_fig5_graph() -> Graph:
@@ -69,4 +70,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

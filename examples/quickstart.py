@@ -18,6 +18,7 @@ from repro.configs import cnn_zoo
 from repro.configs.base import get_config
 from repro.core import DeviceSpec, Engine, init_params, pipeline
 from repro.core.linking import link_groups
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 
 
@@ -74,6 +75,7 @@ def transformer_side():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     cnn_side()
     transformer_side()
     print("\nquickstart OK")

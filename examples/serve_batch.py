@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 from repro.serving import (Request, SamplingParams, ServingEngine,
                            settle_ticks)
@@ -75,4 +76,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -19,6 +19,7 @@ import numpy as np
 from repro.checkpoint import save_checkpoint
 from repro.configs.base import get_config
 from repro.data import SyntheticLM, make_train_iterator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 from repro.optim import cosine_schedule
 
@@ -78,4 +79,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.configs.base import get_config
 from repro.data import audio_batch_stub
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 
 
@@ -44,4 +45,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -150,8 +150,13 @@ def eval_op(node: OpNode, inputs: list[jax.Array],
         return [jax.nn.relu(y + b)]
     if t in ("cbra", "cbrm"):
         pool_attrs = a.get("pool", {})
+        # the kernel's domain: a 1x1 stride-1 dense conv and a 2x2
+        # stride-2 pool over a map of whole 2x2 squares
         if linked_backend == "pallas" and t == "cbra" and a.get("ksize", 1) == 1 \
-                and pool_attrs.get("ksize", 2) == 2:
+                and a.get("stride", 1) == 1 and not a.get("depthwise", False) \
+                and pool_attrs.get("ksize", 2) == 2 \
+                and pool_attrs.get("stride") in (None, 2) \
+                and x.shape[1] % 2 == 0 and x.shape[2] % 2 == 0:
             from repro.kernels.linked_cbr_pool import ops as cbra_ops
             w, b = fold_cbr(node, params)
             return [cbra_ops.cbr_avgpool(x, w, b)]

@@ -1041,11 +1041,14 @@ def select_kernel_plan(options: dict[str, Any] | None = None,
 
       * ``accelerator`` — ``jax.default_backend()`` of the executing
         device (default ``"cpu"``); TPUs route attention and the sampler
-        to the Pallas kernels, hosts keep XLA attention and take the
+        to the Pallas kernels where they tile (``head_dim`` and ``vocab``
+        multiples of 128; the reason for any other choice lands in
+        ``pallas_untiled``), hosts keep XLA attention and take the
         one-sort ``fused`` sampler;
       * ``slots`` / ``q_heads`` / ``kv_heads`` / ``head_dim`` /
         ``max_len`` / ``kv_block_size`` / ``kv_pool_blocks`` — geometry
         for the gather-vs-fold roofline (:func:`_modeled_decode_paged`);
+      * ``vocab`` — the sampler's row width;
       * ``timings`` — ``{"site:backend": seconds}`` measured by the
         micro-benchmark sweep (``launch/autotune.py`` /
         ``tools/kernel_tune.py``); a site with measured candidates takes
@@ -1069,16 +1072,36 @@ def select_kernel_plan(options: dict[str, Any] | None = None,
 
     paged_default, paged_detail = _modeled_decode_paged(o)
     detail.update(paged_detail)
+    # a TPU takes a Pallas kernel only where it tiles the geometry; the
+    # reason for every site left on XLA is recorded, never silent
+    from repro.kernels import LANE
+    from repro.kernels.decode_attention.decode_attention import (
+        tpu_tiling_error)
+    untiled = {}
+    if tpu:
+        err = tpu_tiling_error(int(o.get("head_dim", LANE)))
+        if err:
+            untiled.update(decode_dense=err, decode_paged=err)
+        if int(o.get("vocab", LANE)) % LANE:
+            untiled["sampler"] = f"vocab {o['vocab']} is not a multiple " \
+                                 f"of {LANE}"
+        if untiled:
+            detail["pallas_untiled"] = untiled
+
+    def tpu_pallas(site: str) -> bool:
+        return tpu and site not in untiled
+
     plan = KernelPlan(
         decode_dense=measured("decode_dense")
-        or ("pallas" if tpu else "xla"),
+        or ("pallas" if tpu_pallas("decode_dense") else "xla"),
         decode_paged=measured("decode_paged")
-        or ("pallas" if tpu else paged_default),
+        or ("pallas" if tpu_pallas("decode_paged") else paged_default),
         decode_ring=measured("decode_ring") or "gather",
         prefill_chunk=measured("prefill_chunk") or "xla",
         linked_matmul=measured("linked_matmul")
         or ("pallas" if tpu else "xla"),
-        sampler=measured("sampler") or ("pallas" if tpu else "fused"),
+        sampler=measured("sampler")
+        or ("pallas" if tpu_pallas("sampler") else "fused"),
         ssm_scan=measured("ssm_scan") or "xla",
     )
     return plan, detail

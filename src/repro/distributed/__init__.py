@@ -1,4 +1,4 @@
-from .compat import AxisType, device_count, make_mesh, set_mesh, shard_map
+from .compat import device_count, make_mesh
 from .sharding import (BASELINE_RULES, Rules, activation_spec, batch_axes_for,
                        param_partition_specs, param_shardings, rules_for)
 from .collectives import ps_sync, ring_allreduce
@@ -9,7 +9,7 @@ from .tp import (SERVING_AXIS, SERVING_TP_AXES, serving_cache_specs,
 __all__ = ["Rules", "BASELINE_RULES", "rules_for", "param_partition_specs",
            "param_shardings", "activation_spec", "batch_axes_for",
            "ring_allreduce", "ps_sync",
-           "AxisType", "device_count", "make_mesh", "set_mesh", "shard_map",
+           "device_count", "make_mesh",
            "SERVING_AXIS", "SERVING_TP_AXES", "serving_cache_specs",
            "serving_mesh_shards", "serving_param_specs",
            "validate_serving_tp"]

@@ -21,15 +21,9 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def _axis_size(axis_name: str) -> int:
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)  # old jax: count participants directly
-
-
 def ring_allreduce(x: jax.Array, axis_name: str) -> jax.Array:
     """Chunked ring all-reduce along ``axis_name`` (call inside shard_map)."""
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     if p == 1:
         return x
     rank = lax.axis_index(axis_name)
@@ -66,7 +60,7 @@ def ring_allreduce(x: jax.Array, axis_name: str) -> jax.Array:
 
 def ps_sync(x: jax.Array, axis_name: str) -> jax.Array:
     """Parameter-server emulation: reduce-to-root + broadcast via ring hops."""
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     if p == 1:
         return x
     rank = lax.axis_index(axis_name)

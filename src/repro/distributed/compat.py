@@ -1,39 +1,15 @@
-"""Version compatibility layer over jax's mesh / shard_map API.
+"""The mesh constructor every multi-device path goes through.
 
-Newer jax exposes ``jax.sharding.AxisType`` (mesh axis types),
-``jax.set_mesh`` (ambient mesh context) and ``jax.shard_map`` (with the
-``check_vma`` knob).  Older releases spell these ``with mesh:``,
-``jax.experimental.shard_map.shard_map(check_rep=...)`` and have no axis
-types at all.  Everything in this repo goes through the four names below so
-the multi-device paths (``launch/dryrun.py``, ``tests/test_distributed.py``,
-the sharded MoE) run on both: on old jax the shims degrade to the legacy
-spelling instead of skipping.
+Written against the installed jax (0.9), whose ``jax.sharding.AxisType``,
+``jax.set_mesh`` and ``jax.shard_map(..., check_vma=...)`` callers use
+directly.  ``make_mesh`` adds one thing to ``jax.make_mesh``: it refuses a
+mesh larger than the visible device set with an error that says how to
+get more devices, instead of jax's backend-specific one.
 """
 from __future__ import annotations
 
-import enum
-from typing import Any, Callable
-
 import jax
-
-
-class _AxisTypeStub(enum.Enum):
-    """Stand-in for ``jax.sharding.AxisType`` on jax without axis types.
-
-    Old jax meshes are implicitly fully automatic (GSPMD), which is exactly
-    what every mesh in this repo requests (``AxisType.Auto``), so dropping
-    the annotation is semantics-preserving.
-    """
-
-    Auto = "auto"
-    Explicit = "explicit"
-    Manual = "manual"
-
-
-AxisType: Any = getattr(jax.sharding, "AxisType", _AxisTypeStub)
-
-#: True when the installed jax has native axis types / set_mesh.
-HAS_AXIS_TYPES: bool = hasattr(jax.sharding, "AxisType")
+import numpy as np
 
 
 def device_count() -> int:
@@ -47,12 +23,9 @@ def device_count() -> int:
     return len(jax.devices())
 
 
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...],
-              axis_types: tuple | None = None):
-    """``jax.make_mesh`` that tolerates jax without ``axis_types``.
-
-    ``axis_types`` defaults to all-Auto (the only type this repo uses); on
-    old jax the argument is dropped — legacy meshes are Auto-equivalent.
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with all-Auto axes (the only type this repo
+    uses).
 
     Raises ``ValueError`` (not jax's backend-specific error) when the
     requested mesh is larger than the visible device set, with the
@@ -60,9 +33,7 @@ def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...],
     ``launch/serve.py --mesh-shards`` turn this into a nonzero exit
     instead of silently falling back to fewer devices.
     """
-    import numpy as _np
-
-    need = int(_np.prod(shape))
+    need = int(np.prod(shape))
     have = device_count()
     if need > have:
         raise ValueError(
@@ -70,34 +41,5 @@ def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...],
             f"{have} are visible; on CPU relaunch the process with "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={need} "
             "(the count is locked at first jax backend init)")
-    if axis_types is None:
-        axis_types = (AxisType.Auto,) * len(axes)
-    if HAS_AXIS_TYPES:
-        return jax.make_mesh(shape, axes, axis_types=axis_types)
-    return jax.make_mesh(shape, axes)
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    New jax: ``jax.set_mesh(mesh)``.  Old jax: the ``Mesh`` object itself is
-    a context manager (``with mesh:``) with the same scoping behaviour.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def shard_map(f: Callable, *, mesh, in_specs, out_specs,
-              check_vma: bool = True) -> Callable:
-    """``jax.shard_map`` with the ``check_vma``/``check_rep`` rename bridged.
-
-    ``check_vma=False`` (new) and ``check_rep=False`` (old) both disable the
-    static replication check that hand-built ppermute schedules fail.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _legacy
-    return _legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_vma)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

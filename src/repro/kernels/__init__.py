@@ -2,7 +2,8 @@
 
 Each kernel directory holds:
   * ``<name>.py`` — the pl.pallas_call with explicit BlockSpec VMEM tiling,
-  * ``ops.py``    — the jit'd public wrapper (interpret=True on CPU),
+  * ``ops.py``    — the jit'd public wrapper (interpreted on the CPU
+    backend, compiled on a TPU, refused elsewhere),
   * ``ref.py``    — the pure-jnp oracle tests assert against.
 
 Kernels:
@@ -18,12 +19,21 @@ Kernels:
     to the two-sort reference, which backend a ``KernelPlan`` picks).
 """
 
-INTERPRET_DEFAULT = None  # resolved lazily: True on CPU, False on TPU
+#: TPU vreg lane width: the last dim of a block tiles by it (the second to
+#: last by 8) unless the block spans the array's own dim
+LANE = 128
 
 
 def interpret_mode() -> bool:
-    global INTERPRET_DEFAULT
-    if INTERPRET_DEFAULT is None:
-        import jax
-        INTERPRET_DEFAULT = jax.default_backend() != "tpu"
-    return bool(INTERPRET_DEFAULT)
+    """Whether the kernels run in the Pallas interpreter.
+
+    True on the CPU backend, where tests and CPU runs check the kernels'
+    semantics; False on a TPU, where they compile.  Any other backend
+    raises: no kernel ever falls back to the interpreter in silence."""
+    import jax
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"the Pallas kernels run on a TPU or, interpreted, on the CPU; "
+            f"the default JAX backend is {backend!r}")
+    return backend == "cpu"

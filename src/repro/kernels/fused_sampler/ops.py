@@ -16,9 +16,10 @@ Two backends behind one call:
 
 The categorical draw is shared and identical to the reference
 (``fold_in(key(seed), step)`` then ``jax.random.categorical``), so the
-backend choice never touches the PRNG contract.  ``backend="auto"``
-resolves to the kernel on TPU (lane-aligned vocab) and ``jnp``
-elsewhere — the decision the ``kernel_select`` pass records per plan.
+backend choice never touches the PRNG contract.  The caller names the
+backend — the ``sampler`` site of the ``kernel_select`` pass's plan —
+and the kernel raises on a vocab it cannot tile rather than falling
+back.
 """
 from __future__ import annotations
 
@@ -57,15 +58,9 @@ def _draw_one(row, masked, seed, step, temperature):
                      sampled).astype(jnp.int32)
 
 
-def _resolve(backend: str, vocab: int) -> str:
-    if backend != "auto":
-        return backend
-    return "pallas" if (not interpret_mode() and vocab % 128 == 0) else "jnp"
-
-
 @partial(jax.jit, static_argnames=("vocab", "backend"))
 def fused_sample(logits, seeds, steps, temperature, top_k, top_p, *,
-                 vocab: int, backend: str = "auto"):
+                 vocab: int, backend: str = "jnp"):
     """Batched fused sampling: ``(B, V) -> (B,)`` int32 tokens.
 
     Same signature and PRNG contract as
@@ -73,17 +68,20 @@ def fused_sample(logits, seeds, steps, temperature, top_k, top_p, *,
     the same keyed draw (proven by ``tests/test_fused_sampler.py``).
     """
     rows = logits[..., :vocab].astype(jnp.float32)
-    if _resolve(backend, vocab) == "pallas":
+    if backend == "pallas":
         masked = _kernel_impl(rows, temperature, top_k, top_p,
                               interpret=interpret_mode())
-    else:
+    elif backend == "jnp":
         masked = jax.vmap(_mask_one)(rows, temperature, top_k, top_p)
+    else:
+        raise ValueError(f"unknown sampler backend {backend!r}; "
+                         "have jnp|pallas")
     return jax.vmap(_draw_one)(rows, masked, seeds, steps, temperature)
 
 
 @partial(jax.jit, static_argnames=("vocab", "backend"))
 def fused_sample_grid(logits, seeds, steps, temperature, top_k, top_p, *,
-                      vocab: int, backend: str = "auto"):
+                      vocab: int, backend: str = "jnp"):
     """Speculative-verify sampling: ``(B, K1, V) -> (B, K1)`` tokens,
     keyed ``(seeds[b], steps[b] + i)`` per position exactly like
     ``serving.sampling.sample_token_grid``."""

@@ -8,17 +8,19 @@ and h never round-trips through HBM.
 
 Tiling: grid (M/bm, FF/bff).  The ff axis is the innermost (sequential)
 grid dim so the partial y(bm, d) accumulates in the output block across ff
-steps.  VMEM per step: bm*d (x) + 2*d*bff (W_g, W_u) + bff*d (W_d) +
-bm*bff (h) + bm*d (y) — block shapes chosen so this sits well inside the
-~128 MB v5e VMEM with MXU-aligned (multiple-of-128) matmul dims.
+steps.  VMEM per step: the double-buffered blocks bm*d (x) + 2*d*bff
+(W_g, W_u) + bff*d (W_d) + bm*d (y), plus the f32 temporaries bm*bff (h)
+and bm*d (partial y).  At d=2048 with the 256/512 default blocks that is
+19.4 MB in bf16 and 36.2 MB in f32: more than the 16 MiB scoped-VMEM
+default of a v5e (128 MiB of VMEM in all), so the call sets
+``vmem_limit_bytes`` from this budget (:func:`vmem_budget`).
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref):
@@ -37,6 +39,12 @@ def _kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref):
     @pl.when(j > 0)
     def _acc():
         o_ref[...] += part.astype(o_ref.dtype)
+
+
+def vmem_budget(bm: int, d: int, bff: int, itemsize: int) -> int:
+    """VMEM bytes one grid step holds (see the module docstring)."""
+    blocks = 2 * (bm * d + 2 * d * bff + bff * d + bm * d) * itemsize
+    return blocks + 4 * (bm * bff + bm * d)
 
 
 def linked_mlp(x: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
@@ -60,5 +68,7 @@ def linked_mlp(x: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
         ],
         out_specs=pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, d), x.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=max(
+            16 << 20, vmem_budget(bm, d, bff, x.dtype.itemsize) * 5 // 4)),
         interpret=interpret,
     )(x, wg, wu, wd)

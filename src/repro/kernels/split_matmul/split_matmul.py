@@ -7,8 +7,11 @@ for free (separate output blocks).  The K split is the deprioritized
 inC split — it needs the extra reduction the paper warns about, realized
 here as sequential accumulation over the innermost grid dim.
 
-VMEM claim per step: bm*bk (x) + bk*bn (W) + bm*bn (acc) — all
-MXU-aligned multiples of 128.
+VMEM per step (double-buffered): 2*(bm*bk + bk*bn + bn + bm*bn)
+elements plus the (bm, bn) f32 partial product — 4.5 MiB in f32 at the
+256/512/512 default blocks, inside the 16 MiB scoped-VMEM default of a
+v5e.  TPU tiling: bm a multiple of 8, bk and bn multiples of 128 (or the
+whole dimension).
 """
 from __future__ import annotations
 
@@ -45,9 +48,9 @@ def split_matmul(x: jax.Array, w: jax.Array, b: jax.Array, *,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         interpret=interpret,
-    )(x, w, b)
+    )(x, w, b.reshape(1, N))

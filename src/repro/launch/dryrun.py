@@ -92,7 +92,7 @@ def lower_one(arch: str, shape_name: str, mesh, rules=None, cfg=None,
         jitted = jax.jit(step, in_shardings=(state_shardings, batch_shardings),
                          out_shardings=(state_shardings, repl),
                          donate_argnums=(0,))
-        with mesh_lib.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(state_abs, inputs)
 
     elif shape.kind == "prefill":
@@ -114,7 +114,7 @@ def lower_one(arch: str, shape_name: str, mesh, rules=None, cfg=None,
         jitted = jax.jit(step, in_shardings=(pshard, batch_shardings),
                          out_shardings=(logits_spec,
                                         SS.to_shardings(cache_specs, mesh)))
-        with mesh_lib.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(model.abstract(), inputs)
 
     else:  # decode
@@ -133,7 +133,7 @@ def lower_one(arch: str, shape_name: str, mesh, rules=None, cfg=None,
         jitted = jax.jit(step, in_shardings=(pshard, cache_shardings, tok_shard),
                          out_shardings=(logits_spec, cache_shardings),
                          donate_argnums=(1,))
-        with mesh_lib.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(model.abstract(), caches_abs,
                                    inputs["tokens"])
 

@@ -1,23 +1,17 @@
-"""Production meshes + the jax version-compat shim used to build them.
+"""Production meshes, built through ``repro.distributed.compat``.
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state (jax locks the device count on first backend init).
-
-``AxisType`` / ``make_mesh`` / ``set_mesh`` come from
-``repro.distributed.compat``: on jax without ``sharding.AxisType`` /
-``jax.set_mesh`` they degrade to the legacy spelling (plain meshes, the
-``with mesh:`` context) instead of requiring a newer toolchain — this is
-what lets ``launch/dryrun.py`` and ``tests/test_distributed.py`` run (not
-skip) on older jax.
+``make_mesh`` is ``jax.make_mesh`` with all-Auto axes plus the refusal
+of a mesh larger than the visible devices; install a mesh with
+``jax.set_mesh``.
 """
 from __future__ import annotations
 
-from repro.distributed.compat import (AxisType, HAS_AXIS_TYPES, device_count,
-                                      make_mesh, set_mesh)
+from repro.distributed.compat import device_count, make_mesh
 
-__all__ = ["AxisType", "HAS_AXIS_TYPES", "device_count", "make_mesh",
-           "set_mesh", "make_production_mesh", "make_debug_mesh",
-           "make_serving_mesh"]
+__all__ = ["device_count", "make_mesh", "make_production_mesh",
+           "make_debug_mesh", "make_serving_mesh"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):

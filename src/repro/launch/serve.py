@@ -29,6 +29,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_serving_mesh
 from repro.models.model import Model
 from repro.serving import (ReplicaRouter, Request, SamplingParams,
@@ -147,7 +148,7 @@ def main(argv=None):
             spec_kw["draft_model"] = draft
             spec_kw["draft_params"] = draft.init(
                 jax.random.key(args.seed + 1))
-    def build_engine():
+    def build_engine(device=None):
         return ServingEngine(model, params, slots=args.slots,
                              max_len=args.max_len, chunk=args.chunk,
                              eos_id=args.eos_id,
@@ -155,12 +156,17 @@ def main(argv=None):
                              replan_every=args.replan_every,
                              kv=args.kv, kv_block_size=args.kv_block_size,
                              kv_pool_blocks=args.kv_pool_blocks,
-                             mesh=mesh, **spec_kw)
+                             mesh=mesh, device=device, **spec_kw)
 
     router = None
     if args.replicas > 1:
-        router = ReplicaRouter([build_engine()
-                                for _ in range(args.replicas)])
+        # one-device replicas spread round-robin over the host's devices;
+        # mesh replicas share the mesh
+        devices = jax.devices()
+        router = ReplicaRouter([
+            build_engine(None if mesh is not None
+                         else devices[i % len(devices)])
+            for i in range(args.replicas)])
         engine = router.engines[0]
         submit, step, run_all = router.submit, router.step, router.run
     else:
@@ -262,4 +268,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -137,8 +137,7 @@ def moe_block(p: dict[str, jax.Array], x: jax.Array, *, cfg, mesh=None,
                          e_local=e_local, lo=lo, k_max=k_max)
         return lax.psum(out, "model")
 
-    from repro.distributed.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(bspec, None), P(None, None),
                   P("model", None, None), P("model", None, None),

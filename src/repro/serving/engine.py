@@ -128,7 +128,6 @@ def _serving_jits(model, max_len: int, plan: KernelPlan, mesh=None,
         vocab = model.cfg.vocab
         ax = _tp.SERVING_AXIS if shards > 1 else None
         if shards > 1:
-            from repro.distributed.compat import shard_map as _shard_map
             from jax.sharding import PartitionSpec as _P
             pspecs = _tp.serving_param_specs(model.param_specs())
             cspecs = _tp.serving_cache_specs(caches)
@@ -139,7 +138,7 @@ def _serving_jits(model, max_len: int, plan: KernelPlan, mesh=None,
                 # replicated; check_vma off — unchecked-replication out
                 # specs are exactly what concat-TP produces (each shard
                 # computes the identical full-width result)
-                return jax.jit(_shard_map(
+                return jax.jit(jax.shard_map(
                     f, mesh=mesh,
                     in_specs=(pspecs, cspecs) + (_P(),) * n_rep_args,
                     out_specs=(_P(), cspecs), check_vma=False))
@@ -166,6 +165,13 @@ def _serving_jits(model, max_len: int, plan: KernelPlan, mesh=None,
                 return toks, new_c
 
             serve_sample = wrap(serve_sample_body, 7)
+        if shards > 1:
+            # the logits come back replicated; each shard samples its own
+            # copy (a Pallas kernel cannot be partitioned automatically)
+            sample, sample_grid = (
+                jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(_P(),) * 6,
+                                      out_specs=_P(), check_vma=False))
+                for f in (sample, sample_grid))
 
         cache[key] = {
             "serve": wrap(
@@ -204,9 +210,13 @@ class ServingEngine:
                  spec: SpecParams | None = None, spec_k_max: int = 16,
                  draft_model=None, draft_params=None,
                  kernel_plan: KernelPlan | str | None = None,
-                 kernel_timings: dict | None = None, mesh=None):
+                 kernel_timings: dict | None = None, mesh=None,
+                 device=None):
         if kv not in ("dense", "paged"):
             raise ValueError(f"unknown kv mode {kv!r}; have dense|paged")
+        if device is not None and mesh is not None:
+            raise ValueError("an engine lives on one device or on a mesh, "
+                             "not both")
         from repro.distributed import tp as _tp
         self.model = model
         self.params = params
@@ -214,6 +224,9 @@ class ServingEngine:
         #: an incompatible config fails at construction, not mid-serve
         self.mesh = mesh
         self.mesh_shards = _tp.validate_serving_tp(model.cfg, mesh)
+        #: the one device holding this engine's params and caches (a
+        #: router replica's chip); None = JAX's default device
+        self.device = device
         self.slots = slots
         self.max_len = max_len
         self.eos_id = eos_id
@@ -344,6 +357,17 @@ class ServingEngine:
                                                      kernel_timings)
         self.scheduler.kernel_plan = self.kernel_plan.as_dict()
         self._last_tokens = jnp.zeros((slots, 1), jnp.int32)
+        if device is not None:
+            # every dispatch follows its committed params and caches, so
+            # the whole engine runs on this device
+            self.params = jax.device_put(self.params, device)
+            self.caches = jax.device_put(self.caches, device)
+            self._last_tokens = jax.device_put(self._last_tokens, device)
+            if self._draft is not None:
+                self._draft.params = jax.device_put(self._draft.params,
+                                                    device)
+                self._draft.caches = jax.device_put(self._draft.caches,
+                                                    device)
         if self.mesh_shards > 1:
             # place params/caches under their concat-TP shardings once —
             # otherwise every dispatch would re-shard the replicated
@@ -397,7 +421,7 @@ class ServingEngine:
             "accelerator": jax.default_backend(),
             "slots": self.slots, "max_len": self.max_len,
             "q_heads": cfg.n_heads, "kv_heads": cfg.n_kv_heads,
-            "head_dim": cfg.resolved_head_dim,
+            "head_dim": cfg.resolved_head_dim, "vocab": cfg.vocab,
         }
         if self.mesh_shards > 1:
             options["mesh_shards"] = self.mesh_shards
@@ -643,6 +667,21 @@ class ServingEngine:
         while self.scheduler.pending() and steps < max_steps:
             self.step()
             steps += 1
+
+    def decode_logits(self, plan: KernelPlan | None = None) -> jax.Array:
+        """``(slots, vocab)`` logits the next decode step would give every
+        slot, under this engine's kernel plan or ``plan``, advancing
+        nothing: the probe that compares kernel plans, KV layouts and
+        meshes on one engine state.  Rows of slots not decoding are
+        computed but meaningless."""
+        serve = _serving_jits(self.model, self.max_len,
+                              plan or self.kernel_plan, mesh=self.mesh,
+                              caches=self.caches)["serve"]
+        live = np.asarray([s is not None and s.state == RequestState.DECODE
+                           for s in self.scheduler.active])
+        logits, _ = serve(self.params, self.caches, self._last_tokens,
+                          jnp.asarray(live))
+        return logits[:, :self.model.cfg.vocab]
 
     # -- admission ------------------------------------------------------------
     def _admit(self, plan: TickPlan) -> None:
@@ -1061,6 +1100,8 @@ class ServingEngine:
             out["kernel_report"] = self._kernel_report.as_dict()
         if self.mesh_shards > 1:
             out["mesh_shards"] = self.mesh_shards
+        if self.device is not None:
+            out["device"] = str(self.device)
         if self.pool is not None:
             out["kv_pool"] = self.pool.stats()
             out["prefill_tokens_saved"] = self.pool.tokens_saved

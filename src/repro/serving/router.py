@@ -69,9 +69,11 @@ class ReplicaRouter:
 
     ``engines`` are fully constructed :class:`ServingEngine` replicas
     (same model/params; KV layout and mesh may differ per replica — the
-    router never looks inside).  ``affinity_block`` is the prefix-hash
-    granularity, defaulting to each engine's paged block size when every
-    replica runs a pool, else 16.
+    router never looks inside).  On a host with several devices, build
+    each replica with its own ``device=`` (or mesh): replicas built
+    without one all live on JAX's default device.  ``affinity_block`` is
+    the prefix-hash granularity, defaulting to each engine's paged block
+    size when every replica runs a pool, else 16.
     """
 
     def __init__(self, engines: list[ServingEngine], *,

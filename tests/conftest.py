@@ -1,7 +1,5 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — smoke tests and benches must
 see the real (single) CPU device; multi-device tests spawn subprocesses."""
-import os
-import subprocess
 import sys
 from pathlib import Path
 
@@ -21,11 +19,7 @@ def rng():
 
 
 def run_multidevice(code: str, n_devices: int = 8, timeout: int = 900) -> str:
-    """Run a python snippet in a subprocess with N host-platform devices."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
-    env["PYTHONPATH"] = str(SRC)
-    out = subprocess.run([sys.executable, "-c", code], env=env, timeout=timeout,
-                         capture_output=True, text=True)
-    assert out.returncode == 0, f"STDOUT:\n{out.stdout}\nSTDERR:\n{out.stderr}"
-    return out.stdout
+    """Run a python snippet in a CPU-pinned subprocess with N host-platform
+    devices; a failed child raises with its stderr."""
+    from benchmarks.common import run_on_host_devices
+    return run_on_host_devices(code, n_devices, timeout)

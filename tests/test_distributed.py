@@ -2,8 +2,8 @@
 collective schedules, sharded MoE == oracle, sharded train step, dry-run.
 
 All snippets build meshes through ``repro.distributed.compat`` (re-exported
-by ``repro.launch.mesh``) so they run on jax both with and without
-``sharding.AxisType`` / ``jax.set_mesh`` / ``jax.shard_map``."""
+by ``repro.launch.mesh``), whose ``make_mesh`` refuses a mesh larger than
+the visible device set."""
 import json
 
 import jax
@@ -17,7 +17,7 @@ def test_ring_allreduce_and_ps_equal_psum():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.distributed import ring_allreduce, ps_sync
-from repro.distributed.compat import make_mesh, shard_map
+from repro.distributed.compat import make_mesh
 mesh = make_mesh((8,), ("x",))
 x = jnp.arange(8*33, dtype=jnp.float32).reshape(8, 33)
 def f(kind):
@@ -25,8 +25,8 @@ def f(kind):
         if kind == "ring": return ring_allreduce(xs[0], "x")
         if kind == "ps": return ps_sync(xs[0], "x")
         return jax.lax.psum(xs[0], "x")
-    return jax.jit(shard_map(inner, mesh=mesh, in_specs=P("x", None),
-                             out_specs=P(), check_vma=False))
+    return jax.jit(jax.shard_map(inner, mesh=mesh, in_specs=P("x", None),
+                                 out_specs=P(), check_vma=False))
 want = np.asarray(f("psum")(x))
 for kind in ("ring", "ps"):
     got = np.asarray(f(kind)(x))
@@ -40,7 +40,7 @@ def test_sharded_moe_matches_reference():
     out = run_multidevice("""
 import dataclasses, jax, jax.numpy as jnp, numpy as np
 from repro.configs.base import all_configs
-from repro.distributed.compat import make_mesh, set_mesh
+from repro.distributed.compat import make_mesh
 from repro.models import moe as M
 mesh = make_mesh((2, 4), ("data", "model"))
 cfg = dataclasses.replace(all_configs()["olmoe-1b-7b"].reduced(),
@@ -52,7 +52,7 @@ p = {"router": jnp.asarray(rng.normal(size=(d, 8)), jnp.float32),
      "up": jnp.asarray(rng.normal(size=(8, d, ff))*0.05, jnp.float32),
      "down": jnp.asarray(rng.normal(size=(8, ff, d))*0.05, jnp.float32)}
 x = jnp.asarray(rng.normal(size=(4, 8, d)), jnp.float32)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     out, aux = jax.jit(lambda p, x: M.moe_block(p, x, cfg=cfg, mesh=mesh,
                                                 batch_axes=("data",)))(p, x)
 ref = M.moe_reference(p, x, cfg=cfg)
@@ -87,7 +87,7 @@ _, met1 = jax.jit(lambda s, b: m1.train_step(s, b))(s1, batch)
 
 m2 = Model(cfg, mesh=mesh)
 s2 = m2.init_train_state(jax.random.key(0))
-with mesh_lib.set_mesh(mesh):
+with jax.set_mesh(mesh):
     _, met2 = jax.jit(lambda s, b: m2.train_step(s, b, batch_axes=("data",)))(s2, batch)
 np.testing.assert_allclose(float(met1["loss"]), float(met2["loss"]), rtol=2e-4)
 print("TRAIN_SHARDED_OK", float(met1["loss"]), float(met2["loss"]))

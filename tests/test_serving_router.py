@@ -9,6 +9,8 @@ stream a solo single-request engine emits, greedy and seeded-sampled
 alike, through least-loaded spreading, prefix-affinity stickiness,
 overload spill, and replica failure with at-least-once requeue.
 """
+import json
+
 import numpy as np
 import pytest
 
@@ -209,3 +211,64 @@ def test_router_stats_shape(router_model):
     per = sum(p["decode_tokens_per_s"] for p in s["per_replica"]
               if p and p.get("decode_tokens_per_s"))
     assert s["aggregate_decode_tokens_per_s"] == pytest.approx(per)
+
+
+# -- placement ------------------------------------------------------------------
+
+def test_engine_refuses_both_device_and_mesh(router_model):
+    model, params = router_model
+    with pytest.raises(ValueError, match="one device or on a mesh"):
+        ServingEngine(model, params, device=jax.devices()[0], mesh=object())
+
+
+def test_replicas_live_on_their_own_devices():
+    """Replicas built with ``device=`` hold their params and caches on that
+    device through a whole routed run, and still generate what a solo
+    engine on the first device generates."""
+    from conftest import run_multidevice
+    out = run_multidevice(r"""
+import json
+import jax, numpy as np
+from repro.configs.base import get_config
+from repro.models.model import Model
+from repro.serving import Request, ServingEngine
+from repro.serving.router import ReplicaRouter
+
+cfg = get_config("qwen3-1.7b").reduced()
+model = Model(cfg)
+params = model.init(jax.random.key(0))
+devices = jax.devices()
+
+def engine(d):
+    return ServingEngine(model, params, slots=2, max_len=64, chunk=8,
+                         prefill_mode="chunked", kv="paged",
+                         kv_block_size=8, device=d)
+
+def on(tree, d):
+    return all(x.devices() == {d} for x in jax.tree.leaves(tree))
+
+def requests():
+    rng = np.random.default_rng(0)
+    return [Request(rid=i, prompt=rng.integers(0, cfg.vocab, 12)
+                    .astype(np.int32), max_new_tokens=4) for i in range(4)]
+
+solo = engine(devices[0])
+want = requests()
+for r in want:
+    solo.submit(r)
+solo.run()
+router = ReplicaRouter([engine(d) for d in devices])
+got = requests()
+for r in got:
+    router.submit(r)
+router.run()
+for e, d in zip(router.engines, devices):
+    assert on(e.params, d) and on(e.caches, d), d
+    assert e.stats()["device"] == str(d)
+assert all(r.done for r in got)
+assert [r.generated for r in got] == [r.generated for r in want]
+print("PLACED " + json.dumps(
+    [s["tokens_out"] for s in router.stats()["per_replica"]]))
+""", n_devices=2)
+    per = json.loads(out.split("PLACED ", 1)[1])
+    assert len(per) == 2 and all(n > 0 for n in per)
