@@ -12,8 +12,7 @@ import time
 import traceback
 
 from benchmarks import (fig7_inference_time, fig8_framework, fig11_dxenos,
-                        roofline, serving_throughput, table2_auto_time,
-                        table4_operators)
+                        roofline, table2_auto_time, table4_operators)
 from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = {
@@ -23,7 +22,6 @@ SUITES = {
     "table4": table4_operators.run,
     "fig11": fig11_dxenos.run,
     "roofline": roofline.run,
-    "serving": serving_throughput.run,
 }
 
 

@@ -41,10 +41,17 @@ serving-equivalence harness (``tests/test_serving_fuzz.py``) keeps the two
 bit-identical under greedy and seeded sampling.
 
 The engine shares the optimization pipeline's stage instrumentation
-(``repro.core.pipeline.StageTimer``): every stage is timed, and ``stats()``
-returns the same structured per-stage record the pass manager emits plus
-the scheduler's current serve_schedule plan — serving traces and
-PassReports read alike.
+(``repro.core.pipeline.StageTimer``): every stage of a tick is timed on
+the host clock, and ``stats()`` returns the same structured per-stage
+record the pass manager emits plus the scheduler's current serve_schedule
+plan.  Each stage is also a ``serving.``-prefixed span (``serving.step``,
+``serving.plan``, ``serving.admit``, ``serving.prefill_chunk``,
+``serving.decode``, ``serving.verify``, ``serving.replan``; the model
+stages split into ``.inputs``, ``.dispatch``, ``.wait`` and ``.emit``) in
+any active ``jax.profiler`` session, on the device trace's clock.  A
+request's ``queued_s`` is its time waiting for a slot, and a paged
+engine's ``stats()["kv_pool"]`` sums the blocks leased and the blocks
+written over ticks (``docs/serving.md``, "Tracing").
 """
 from __future__ import annotations
 
@@ -80,6 +87,9 @@ class Request:
     spec: SpecParams | None = None
     generated: list = dataclasses.field(default_factory=list)
     done: bool = False
+    #: seconds spent waiting for a slot, summed over every admission (a
+    #: preempted request waits again); None until first admitted
+    queued_s: float | None = None
 
 
 def settle_ticks(prompt_len: int, chunk: int) -> int:
@@ -259,7 +269,7 @@ class ServingEngine:
             sampling = SamplingParams() if greedy \
                 else SamplingParams(temperature=1.0)
         self.default_sampling = sampling
-        self.timer = StageTimer()
+        self.timer = StageTimer(prefix="serving.")
         self.tokens_out = 0        # every generated token (prefill + decode)
         self._decode_tokens = 0    # decode-loop tokens only (throughput)
         self._prefill_tokens = 0   # prompt tokens pushed through prefill
@@ -639,27 +649,31 @@ class ServingEngine:
     def step(self) -> int:
         """One engine tick: execute the scheduler's plan.  Returns the
         number of slots that produced a token this tick."""
-        plan = self.scheduler.plan_tick()
-        produced = 0
-        if plan.admissions:
-            with self.timer.stage("admit"):
-                self._admit(plan)
-            if self.scheduler.cfg.prefill_mode != "chunked":
-                produced += len(plan.admissions)
-        if plan.prefill:
-            with self.timer.stage("prefill_chunk"):
-                produced += self._prefill_chunks(plan)
-        if plan.decode_slots:
-            drafts = self._plan_drafts(plan)
-            if drafts:
-                with self.timer.stage("verify"):
-                    produced += self._decode_verify(plan, drafts)
-            else:
-                # no slot drafted this tick: the plain one-token decode
-                # dispatch, exactly as a spec=off engine would run it
-                with self.timer.stage("decode"):
-                    produced += self._decode(plan)
-        self._maybe_replan()
+        with self.timer.stage("step"):
+            with self.timer.stage("plan"):
+                plan = self.scheduler.plan_tick()
+            produced = 0
+            if plan.admissions:
+                with self.timer.stage("admit"):
+                    self._admit(plan)
+                if self.scheduler.cfg.prefill_mode != "chunked":
+                    produced += len(plan.admissions)
+            if plan.prefill:
+                with self.timer.stage("prefill_chunk"):
+                    produced += self._prefill_chunks(plan)
+            if plan.decode_slots:
+                drafts = self._plan_drafts(plan)
+                if drafts:
+                    with self.timer.stage("verify"):
+                        produced += self._decode_verify(plan, drafts)
+                else:
+                    # no slot drafted this tick: the plain one-token decode
+                    # dispatch, exactly as a spec=off engine would run it
+                    with self.timer.stage("decode"):
+                        produced += self._decode(plan)
+            if self.pool is not None:
+                self._tally_kv_use()
+            self._maybe_replan()
         return produced
 
     def run(self, max_steps: int = 10_000) -> None:
@@ -773,7 +787,8 @@ class ServingEngine:
         if padded:
             batch["lengths"] = jnp.asarray(lens, jnp.int32)
         logits, fresh = self._prefill(self.params, batch)
-        jax.block_until_ready(logits)
+        with self.timer.stage(".wait"):
+            jax.block_until_ready(logits)
         slots_arr = jnp.asarray([s.slot for s in group], jnp.int32)
         # splice the freshly prefilled rows into their slots' cache rows;
         # heterogeneous tuples' leaves are batch-major (no layer axis)
@@ -792,42 +807,47 @@ class ServingEngine:
     # -- chunked prefill ------------------------------------------------------
     def _prefill_chunks(self, plan: TickPlan) -> int:
         C = self.scheduler.cfg.chunk
-        toks = np.zeros((self.slots, C), np.int32)
-        offsets = np.zeros((self.slots,), np.int32)
-        n_new = np.zeros((self.slots,), np.int32)
-        rows: list = [None] * self.slots
-        for a in plan.prefill:
-            toks[a.slot, :a.n_new] = \
-                a.sreq.prompt_tokens[a.start:a.start + a.n_new]
-            offsets[a.slot] = a.start
-            n_new[a.slot] = a.n_new
-            rows[a.slot] = a.sreq
-        logits, self.caches = self._chunk_step(
-            self.params, self.caches, jnp.asarray(toks),
-            jnp.asarray(offsets), jnp.asarray(n_new))
+        with self.timer.stage(".inputs"):
+            toks = np.zeros((self.slots, C), np.int32)
+            offsets = np.zeros((self.slots,), np.int32)
+            n_new = np.zeros((self.slots,), np.int32)
+            rows: list = [None] * self.slots
+            for a in plan.prefill:
+                toks[a.slot, :a.n_new] = \
+                    a.sreq.prompt_tokens[a.start:a.start + a.n_new]
+                offsets[a.slot] = a.start
+                n_new[a.slot] = a.n_new
+                rows[a.slot] = a.sreq
+        with self.timer.stage(".dispatch"):
+            logits, self.caches = self._chunk_step(
+                self.params, self.caches, jnp.asarray(toks),
+                jnp.asarray(offsets), jnp.asarray(n_new))
         if any(a.start + a.n_new >= a.sreq.prompt_len for a in plan.prefill):
             toks_out = self._sample(logits, rows)
         else:
             # no slot finishes its prompt this tick: the logits are dead,
             # skip the sampling dispatch (but still sync for stage timing)
             toks_out = None
-            jax.block_until_ready(logits)
+            with self.timer.stage(".wait"):
+                jax.block_until_ready(logits)
         produced = 0
-        for a in plan.prefill:
-            self._prefill_tokens += a.n_new
-            done = a.start + a.n_new >= a.sreq.prompt_len
-            first = int(toks_out[a.slot]) if done else None
-            if self.pool is not None:
-                # register freshly *full* prefill blocks in the prefix
-                # cache (before note_prefilled: its _emit may retire the
-                # request and release the lease in the same call)
-                self.pool.note_prefilled(a.sreq.req.rid, a.start + a.n_new)
-            if done:
-                self._last_tokens = \
-                    self._last_tokens.at[a.slot, 0].set(first)
-                self.tokens_out += 1
-                produced += 1
-            self.scheduler.note_prefilled(a.sreq, a.n_new, first)
+        with self.timer.stage(".emit"):
+            for a in plan.prefill:
+                self._prefill_tokens += a.n_new
+                done = a.start + a.n_new >= a.sreq.prompt_len
+                first = int(toks_out[a.slot]) if done else None
+                if self.pool is not None:
+                    # register freshly *full* prefill blocks in the prefix
+                    # cache (before note_prefilled: its _emit may retire
+                    # the request and release the lease in the same call)
+                    self.pool.note_prefilled(a.sreq.req.rid,
+                                             a.start + a.n_new)
+                if done:
+                    self._last_tokens = \
+                        self._last_tokens.at[a.slot, 0].set(first)
+                    self.tokens_out += 1
+                    produced += 1
+                self.scheduler.note_prefilled(a.sreq, a.n_new, first)
         return produced
 
     # -- speculative decode ---------------------------------------------------
@@ -898,7 +918,8 @@ class ServingEngine:
         n_new = np.zeros((B,), np.int32)
         rows: list = [None] * B
         pre_len = np.zeros((B,), np.int64)
-        last = np.asarray(self._last_tokens)[:, 0]
+        with self.timer.stage(".wait"):
+            last = np.asarray(self._last_tokens)[:, 0]
         for slot in plan.decode_slots:
             sreq = self.scheduler.active[slot]
             rows[slot] = sreq
@@ -913,7 +934,7 @@ class ServingEngine:
                              + len(sreq.req.generated) - 1)
         logits, self.caches = self._verify(
             self.params, self.caches, jnp.asarray(toks), jnp.asarray(n_new))
-        targets = self._sample_grid(logits, rows)
+        targets = self._sample(logits, rows, self._sample_grid_step)
         self.spec_stats.verify_calls += 1
         self.spec_stats.verify_positions += int(n_new.sum())
 
@@ -975,32 +996,40 @@ class ServingEngine:
 
     # -- decode ---------------------------------------------------------------
     def _decode(self, plan: TickPlan) -> int:
-        live = np.zeros((self.slots,), bool)
-        rows: list = [None] * self.slots
-        for slot in plan.decode_slots:
-            live[slot] = True
-            rows[slot] = self.scheduler.active[slot]
-        if self._serve_sample is not None:
+        fused = self._serve_sample is not None
+        with self.timer.stage(".inputs"):
+            live = np.zeros((self.slots,), bool)
+            rows: list = [None] * self.slots
+            for slot in plan.decode_slots:
+                live[slot] = True
+                rows[slot] = self.scheduler.active[slot]
+            if fused:
+                seeds, steps, temps, ks, ps = self._sampling_arrays(rows)
+        if fused:
             # fused-sampler plan: decode + sampling in ONE jitted dispatch
             # (the fused sampler's draw handles temperature-0 rows as
             # argmax internally, so greedy needs no separate shortcut)
-            seeds, steps, temps, ks, ps = self._sampling_arrays(rows)
-            toks, self.caches = self._serve_sample(
-                self.params, self.caches, self._last_tokens,
-                jnp.asarray(live), jnp.asarray(seeds), jnp.asarray(steps),
-                jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(ps))
-            toks = np.asarray(jax.block_until_ready(toks))
+            with self.timer.stage(".dispatch"):
+                toks, self.caches = self._serve_sample(
+                    self.params, self.caches, self._last_tokens,
+                    jnp.asarray(live), jnp.asarray(seeds),
+                    jnp.asarray(steps), jnp.asarray(temps), jnp.asarray(ks),
+                    jnp.asarray(ps))
+            with self.timer.stage(".wait"):
+                toks = np.asarray(jax.block_until_ready(toks))
         else:
-            logits, self.caches = self._serve(self.params, self.caches,
-                                              self._last_tokens,
-                                              jnp.asarray(live))
+            with self.timer.stage(".dispatch"):
+                logits, self.caches = self._serve(self.params, self.caches,
+                                                  self._last_tokens,
+                                                  jnp.asarray(live))
             toks = self._sample(logits, rows)
-        for slot in plan.decode_slots:
-            t = int(toks[slot])
-            self.tokens_out += 1
-            self._decode_tokens += 1
-            self._last_tokens = self._last_tokens.at[slot, 0].set(t)
-            self.scheduler.note_decoded(slot, t)
+        with self.timer.stage(".emit"):
+            for slot in plan.decode_slots:
+                t = int(toks[slot])
+                self.tokens_out += 1
+                self._decode_tokens += 1
+                self._last_tokens = self._last_tokens.at[slot, 0].set(t)
+                self.scheduler.note_decoded(slot, t)
         return len(plan.decode_slots)
 
     # -- sampling -------------------------------------------------------------
@@ -1028,63 +1057,65 @@ class ServingEngine:
             ps[i] = sp.top_p
         return seeds, steps, temps, ks, ps
 
-    def _sample(self, logits: jax.Array, rows) -> np.ndarray:
+    def _sample(self, logits: jax.Array, rows, step=None) -> np.ndarray:
         """One batched sampling dispatch over ``(B, V)`` logits (the
-        prefill paths, and decode under the reference-sampler plan)."""
-        seeds, steps, temps, ks, ps = self._sampling_arrays(rows)
-        if not temps.any():
-            # all-greedy batch: plain argmax, skip the sort/cumsum sampler
-            toks = jnp.argmax(logits[..., :self.model.cfg.vocab],
-                              axis=-1).astype(jnp.int32)
+        prefill paths, and decode under the reference-sampler plan), timed
+        as children of the open stage.  A verify tick passes
+        ``step=self._sample_grid_step`` for ``(B, K1, V)`` logits: position
+        ``i`` of row ``b`` uses key ``(seed_b, emitted_b + i)`` — the same
+        keys the plain decode path would use emitting those tokens one
+        tick at a time (``sample_token_grid``), which is what makes
+        speculative sampled streams identical, not merely equal in
+        distribution."""
+        with self.timer.stage(".inputs"):
+            seeds, steps, temps, ks, ps = self._sampling_arrays(rows)
+        with self.timer.stage(".dispatch"):
+            if not temps.any():
+                # all-greedy batch: plain argmax, skip the sort/cumsum
+                # sampler
+                toks = jnp.argmax(logits[..., :self.model.cfg.vocab],
+                                  axis=-1).astype(jnp.int32)
+            else:
+                toks = (step or self._sample_step)(
+                    logits, jnp.asarray(seeds), jnp.asarray(steps),
+                    jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(ps))
+        with self.timer.stage(".wait"):
             return np.asarray(jax.block_until_ready(toks))
-        toks = self._sample_step(logits, jnp.asarray(seeds),
-                                 jnp.asarray(steps), jnp.asarray(temps),
-                                 jnp.asarray(ks), jnp.asarray(ps))
-        return np.asarray(jax.block_until_ready(toks))
-
-    def _sample_grid(self, logits: jax.Array, rows) -> np.ndarray:
-        """Verify-tick sampling over ``(B, K1, V)`` logits: position ``i``
-        of row ``b`` uses key ``(seed_b, emitted_b + i)`` — the same keys
-        the plain decode path would use emitting those tokens one tick at
-        a time (``sample_token_grid``), which is what makes speculative
-        sampled streams identical, not merely equal in distribution."""
-        seeds, steps, temps, ks, ps = self._sampling_arrays(rows)
-        if not temps.any():
-            toks = jnp.argmax(logits[..., :self.model.cfg.vocab],
-                              axis=-1).astype(jnp.int32)
-            return np.asarray(jax.block_until_ready(toks))
-        toks = self._sample_grid_step(logits, jnp.asarray(seeds),
-                                      jnp.asarray(steps), jnp.asarray(temps),
-                                      jnp.asarray(ks), jnp.asarray(ps))
-        return np.asarray(jax.block_until_ready(toks))
 
     # -- re-planning / stats --------------------------------------------------
+    def _tally_kv_use(self) -> None:
+        """Add this tick's KV reservation use to the pool's totals: each
+        live request's context written so far (a decoding row's newest
+        token is not written until the next step)."""
+        written = {}
+        for s in self.scheduler.active:
+            if s is not None:
+                written[s.req.rid] = (
+                    s.pos if s.state is RequestState.PREFILL
+                    else len(s.req.prompt) + len(s.req.generated) - 1)
+        self.pool.tally_use(written)
+
     def _maybe_replan(self) -> None:
-        import time
-        # verify dispatches are the spec engine's decode steps: fold them
-        # in so a mostly-speculative workload still produces decode stats
-        decode = (self.timer.totals.get("decode", 0.0)
-                  + self.timer.totals.get("verify", 0.0))
-        decode_calls = (self.timer.counts.get("decode", 0)
-                        + self.timer.counts.get("verify", 0))
-        prefill_s = (self.timer.totals.get("prefill_chunk", 0.0)
-                     + self.timer.totals.get("admit", 0.0))
-        accept = None
-        if self.default_spec.mode != "off" \
-                and self.spec_stats.drafts_proposed:
-            accept = self.spec_stats.accept_rate
-        t0 = time.perf_counter()
-        plan = self.scheduler.maybe_replan(
-            decode_step_s=decode / decode_calls if decode_calls else 0.0,
-            prefill_token_s=prefill_s / self._prefill_tokens
-            if self._prefill_tokens else 0.0,
-            accept_rate=accept)
-        if plan is not None:  # record only ticks that actually re-planned
-            dt = time.perf_counter() - t0
-            self.timer.totals["replan"] = \
-                self.timer.totals.get("replan", 0.0) + dt
-            self.timer.counts["replan"] = \
-                self.timer.counts.get("replan", 0) + 1
+        if not self.scheduler.replan_due():
+            return
+        with self.timer.stage("replan"):
+            # verify dispatches are the spec engine's decode steps: fold
+            # them in so a mostly-speculative workload still produces
+            # decode stats
+            totals, counts = self.timer.totals, self.timer.counts
+            decode = totals.get("decode", 0.0) + totals.get("verify", 0.0)
+            decode_calls = counts.get("decode", 0) + counts.get("verify", 0)
+            prefill_s = (totals.get("prefill_chunk", 0.0)
+                         + totals.get("admit", 0.0))
+            accept = None
+            if self.default_spec.mode != "off" \
+                    and self.spec_stats.drafts_proposed:
+                accept = self.spec_stats.accept_rate
+            self.scheduler.maybe_replan(
+                decode_step_s=decode / decode_calls if decode_calls else 0.0,
+                prefill_token_s=prefill_s / self._prefill_tokens
+                if self._prefill_tokens else 0.0,
+                accept_rate=accept)
 
     def stats(self) -> dict:
         """Per-stage timing + throughput + the scheduler's plan,

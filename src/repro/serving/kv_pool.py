@@ -134,6 +134,10 @@ class KVBlockPool:
         #: rids ever deferred by the admission gate (a blocked queue head
         #: is re-polled every tick — count requests, not polls)
         self.gated_rids: set[int] = set()
+        #: reservation use, summed over engine ticks (``tally_use``):
+        #: blocks leased, and the blocks of those holding written context
+        self.leased_block_ticks = 0
+        self.written_block_ticks = 0
 
     # -- capacity -----------------------------------------------------------
     def blocks_for(self, n_tokens: int) -> int:
@@ -391,6 +395,16 @@ class KVBlockPool:
             freed += 1
         return freed
 
+    def tally_use(self, written: dict[int, int]) -> None:
+        """Add one tick of reservation use: the blocks of every live lease,
+        and of those the ones its ``written[rid]`` context tokens occupy
+        (capped at the lease; a ring lease's context wraps in place)."""
+        bs = self.cfg.block_size
+        for rid, lease in self.leases.items():
+            n = len(lease.blocks)
+            self.leased_block_ticks += n
+            self.written_block_ticks += min(-(-written.get(rid, 0) // bs), n)
+
     # -- introspection ------------------------------------------------------
     def block_table(self, rid: int) -> np.ndarray:
         """The request's block table row, -1-padded to the table width."""
@@ -412,6 +426,8 @@ class KVBlockPool:
             "prefill_tokens_saved": self.tokens_saved,
             "gated_requests": len(self.gated_rids),
             "live_requests": len(self.leases),
+            "leased_block_ticks": self.leased_block_ticks,
+            "written_block_ticks": self.written_block_ticks,
         }
 
     def check_invariants(self) -> None:
@@ -546,6 +562,10 @@ class MixedKVPool:
         self.classic.free(rid)
         self.ring.free(rid)
 
+    def tally_use(self, written: dict[int, int]) -> None:
+        self.classic.tally_use(written)
+        self.ring.tally_use(written)
+
     def truncate(self, rid: int, n_tokens: int) -> int:
         # spec decoding (the one truncate caller) is gated off for mixed
         # stacks; classic-only keeps the hook total if that ever changes
@@ -563,7 +583,8 @@ class MixedKVPool:
         c, r = self.classic.stats(), self.ring.stats()
         merged = dict(c)
         for k in ("pool_blocks", "blocks_in_use", "blocks_free",
-                  "blocks_cached"):
+                  "blocks_cached", "leased_block_ticks",
+                  "written_block_ticks"):
             merged[k] = c[k] + r[k]
         merged["kind"] = "mixed"
         merged["kv_window"] = self.window

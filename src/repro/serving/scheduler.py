@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import time
 from collections import deque
 from typing import Any
 
@@ -58,6 +59,7 @@ class ScheduledRequest:
     pos: int = 0                     # prompt tokens prefilled so far
     seq: int = 0                     # submission order (FIFO evidence)
     preemptions: int = 0             # times this request was evicted
+    queued_at: float = 0.0           # scheduler clock when it (re)queued
 
     @property
     def prompt_tokens(self) -> np.ndarray:
@@ -135,10 +137,13 @@ def _quantize(x: float) -> float:
 class Scheduler:
     """Admission policy + chunk budgeting + lifecycle FSM over fixed slots."""
 
-    def __init__(self, cfg: SchedulerConfig, plan_graph=None):
+    def __init__(self, cfg: SchedulerConfig, plan_graph=None,
+                 clock=time.perf_counter):
         if cfg.prefill_mode not in ("chunked", "batched", "serial"):
             raise ValueError(f"unknown prefill_mode {cfg.prefill_mode!r}")
         self.cfg = cfg
+        #: stamps queue entry and admission (``Request.queued_s``)
+        self.clock = clock
         #: a caller-set admission cap is pinned; only a None (= every free
         #: slot) cap is replaced by the serve_schedule plan's ``admit``
         self._admit_pinned = cfg.admit is not None
@@ -215,7 +220,8 @@ class Scheduler:
             raise ValueError(
                 f"request {getattr(req, 'rid', '?')} has an empty prompt: "
                 "there is no position to sample a first token from")
-        sreq = ScheduledRequest(req=req, seq=self._seq)
+        sreq = ScheduledRequest(req=req, seq=self._seq,
+                                queued_at=self.clock())
         self._seq += 1
         self.waiting.append(sreq)
         self._waiting_dirty = True
@@ -226,6 +232,10 @@ class Scheduler:
 
     def _place(self, sreq: ScheduledRequest, slot: int,
                plan: TickPlan) -> None:
+        # every admission adds the wait since the request (re)entered the
+        # queue: at submission, or at its last preemption
+        sreq.req.queued_s = ((sreq.req.queued_s or 0.0)
+                             + self.clock() - sreq.queued_at)
         sreq.slot = slot
         sreq.state = RequestState.PREFILL
         self.active[slot] = sreq
@@ -335,6 +345,7 @@ class Scheduler:
         sreq.state = RequestState.WAITING
         sreq.preemptions += 1
         self.preempted += 1
+        sreq.queued_at = self.clock()
         if self.on_release is not None:
             self.on_release(sreq)
         self.waiting.append(sreq)
@@ -393,6 +404,11 @@ class Scheduler:
         return bool(self.waiting) or any(s is not None for s in self.active)
 
     # -- re-planning through the pass manager ---------------------------------
+    def replan_due(self) -> bool:
+        """Does this tick run the ``serve_schedule`` pass?"""
+        return self.plan_graph is not None \
+            and not self._ticks % self.cfg.replan_every
+
     def maybe_replan(self, decode_step_s: float, prefill_token_s: float,
                      device=None,
                      accept_rate: float | None = None) -> dict[str, Any] | None:
@@ -403,7 +419,7 @@ class Scheduler:
         engine also feeds its observed draft ``accept_rate`` (None = no
         drafts verified yet) and adopts the planned ``spec_k``.  Returns
         the plan on replan ticks, None otherwise."""
-        if self.plan_graph is None or self._ticks % self.cfg.replan_every:
+        if not self.replan_due():
             return None
         from repro.core import pipeline  # serving depends on core, not back
 
