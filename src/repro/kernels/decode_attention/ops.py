@@ -30,9 +30,10 @@ def gqa_decode(q, k_cache, v_cache, valid, *, block_w: int = 1024):
 
 @jax.jit
 def gqa_decode_paged(q, k_pool, v_pool, block_tables, lengths):
-    """Paged flash-decode: the block table is scalar-prefetched so each
-    grid step DMAs one physical pool block (no dense gather).  Compiled,
-    a pool the TPU cannot tile raises."""
+    """Paged flash-decode: one program per row copies that row's live
+    pool pages, several whole pages per DMA chunk (no dense gather).
+    Compiled, a pool the TPU cannot tile raises; interpreted, the kernel
+    runs under the TPU interpreter, which simulates its DMAs."""
     if not interpret_mode():
         err = tpu_tiling_error(k_pool.shape[3])
         if err:

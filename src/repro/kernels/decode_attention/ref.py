@@ -20,11 +20,13 @@ def gqa_decode_ref(q, k_cache, v_cache, valid):
 
 def gqa_decode_paged_ref(q, k_pool, v_pool, block_tables, lengths):
     """Paged oracle: gather the pages into a dense per-request view, then
-    run the dense oracle with a length mask."""
+    run the dense oracle with a length mask.  A row of length 0 attends to
+    nothing and reads zeros, as the kernel does."""
     B, M = block_tables.shape
     bs = k_pool.shape[1]
     bt = jnp.maximum(block_tables, 0)
     k = k_pool[bt].reshape(B, M * bs, *k_pool.shape[2:])
     v = v_pool[bt].reshape(B, M * bs, *v_pool.shape[2:])
     valid = jnp.arange(M * bs)[None, :] < lengths[:, None]
-    return gqa_decode_ref(q, k, v, valid)
+    out = gqa_decode_ref(q, k, v, valid)
+    return jnp.where(lengths[:, None, None] > 0, out, 0).astype(out.dtype)

@@ -594,8 +594,12 @@ def _paged_decode_write_attend(q: jax.Array, k_new: jax.Array,
     v_pool = cache.v.at[safe_blk, off].set(
         v_new.astype(cache.v.dtype), mode="drop")
     new_len = jnp.where(ok, pos + 1, pos).astype(jnp.int32)
+    # the kernel walks each row's live pages: a row left out of this step
+    # walks none (its output is discarded); gather/fold keep their bits
+    attend_len = jnp.where(live, new_len, 0) if backend == "pallas" \
+        else new_len
     out = decode_attention_paged(q, k_pool, v_pool, cache.block_tables,
-                                 new_len, backend)
+                                 attend_len, backend)
     return out, PagedKVCache(k=k_pool, v=v_pool,
                              block_tables=cache.block_tables, length=new_len)
 

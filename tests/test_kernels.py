@@ -114,6 +114,57 @@ def test_gqa_decode_paged_sweep(B, H, K, D, bs, M):
                                rtol=3e-5, atol=3e-5)
 
 
+#: (lengths, K, D, bs, M, pages per chunk or None for the kernel's own,
+#: dtype): empty rows, ends mid-page, on a page, on a chunk and at the
+#: full table, at toy widths and at qwen3-1.7b's (K 8, D 128, bs 32)
+PAGED_EDGES = {
+    "edges_f32": ([0, 5, 8, 16, 27, 48], 2, 32, 8, 6, 2, jnp.float32),
+    "one_chunk_f32": ([0, 13, 64], 2, 64, 8, 8, None, jnp.float32),
+    "qwen3_bf16": ([0, 31, 64, 128], 8, 128, 32, 4, 2, jnp.bfloat16),
+    "qwen3_own_chunk_bf16": ([1, 100, 0], 8, 128, 32, 4, None,
+                             jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAGED_EDGES))
+def test_gqa_decode_paged_walks_live_pages_only(monkeypatch, case):
+    """Row lengths at every page and chunk edge, ``-1`` table entries past
+    the live pages, and every pool position outside the live context —
+    whole blocks no row owns and the tail of each row's last page — set
+    to NaN in K and Inf in V: the kernel matches the oracle on clean
+    pools, so nothing past a row's length reaches its output."""
+    from repro.kernels.decode_attention import decode_attention as DA
+    lengths, K, D, bs, M, C, dtype = PAGED_EDGES[case]
+    if C is not None:
+        itemsize = jnp.dtype(dtype).itemsize
+        monkeypatch.setattr(DA, "PAGE_BUFFER_BYTES",
+                            C * bs * K * D * itemsize)
+        assert DA.pages_per_chunk(bs, K * D * itemsize, M) == C
+    B, H = len(lengths), 2 * K
+    P = B * M + 2
+    r = np.random.default_rng(11)
+    kp, vp = r.normal(size=(2, P, bs, K, D))
+    bt = np.full((B, M), -1, np.int32)
+    live = np.zeros((P, bs), bool)
+    blocks = iter(r.permutation(P))
+    for b, n in enumerate(lengths):
+        for m in range(-(-n // bs)):
+            bt[b, m] = next(blocks)
+            live[bt[b, m], :min(bs, n - m * bs)] = True
+    clean_k, clean_v = (jnp.asarray(np.where(live[..., None, None], x, 0.0),
+                                    dtype) for x in (kp, vp))
+    kp[~live], vp[~live] = np.nan, np.inf
+    q = jnp.asarray(r.normal(size=(B, H, D)), dtype)
+    tables, n = jnp.asarray(bt), jnp.asarray(lengths, jnp.int32)
+    out = DA.gqa_decode_paged(q, jnp.asarray(kp, dtype),
+                              jnp.asarray(vp, dtype), tables, n,
+                              interpret=True)
+    ref = da_ref.gqa_decode_paged_ref(q, clean_k, clean_v, tables, n)
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    np.testing.assert_allclose(out, ref, **TOL[dtype])
+    assert not out[np.asarray(lengths) == 0].any()
+
+
 @given(bs=st.sampled_from([8, 16]), m=st.sampled_from([2, 4]),
        seed=st.integers(0, 2**16))
 @settings(max_examples=10, deadline=None)

@@ -63,17 +63,23 @@ def test_gqa_decode_compiles(shape, block_w):
         shape((B, W, K, D), BF16), shape((B, W), jnp.bool_))
 
 
-@pytest.mark.parametrize("block_size", [8, 16, 32])
-def test_gqa_decode_paged_compiles(shape, block_size):
+@pytest.mark.parametrize("slots,block_size,max_blocks,pool_blocks", [
+    (B, 8, W // 8, B * W // 8), (B, 16, W // 16, B * W // 16),
+    (B, 32, W // 32, B * W // 32), (32, 32, 128, 1536)],
+    ids=["8", "16", "32", "qwen3_chat"])
+def test_gqa_decode_paged_compiles(shape, slots, block_size, max_blocks,
+                                   pool_blocks):
+    """At 8-32-token pages, and at the qwen3-chat cell's own geometry: 32
+    slots of 128 table blocks over a 1,536-block pool, whose 16-page
+    chunks fill the kernel's page buffers."""
     from repro.kernels.decode_attention.decode_attention import (
         gqa_decode_paged)
-    M = W // block_size
-    pool = shape((B * M, block_size, K, D), BF16)
+    pool = shape((pool_blocks, block_size, K, D), BF16)
     compile_for_chip(
         lambda q, k, v, t, n: gqa_decode_paged(q, k, v, t, n,
                                                interpret=False),
-        shape((B, H, D), BF16), pool, pool, shape((B, M), jnp.int32),
-        shape((B,), jnp.int32))
+        shape((slots, H, D), BF16), pool, pool,
+        shape((slots, max_blocks), jnp.int32), shape((slots,), jnp.int32))
 
 
 def test_fused_mask_compiles_at_qwen3_vocab(shape):
