@@ -3,13 +3,14 @@
 Once the window has closed and the program's state is freed, a sample of
 the requests the run finished (drawn from the seed, the greedy and the
 sampled request with the most output tokens always in it) goes through
-the plain float32 reference (``bench/reference``), each prompt with its
-served tokens.  At every served position the reference gives the logits
-of the next token, and from them the set of tokens the request allows
-there: the best one for a greedy request; for a sampled one the tokens
-that survive its temperature, top-k and top-p filter, as the published
-sampling rule defines it (keep the ``k`` highest tempered logits, then the
-shortest run of them, from the top, whose renormalised mass reaches
+the plain float32 reference of the configuration's architecture
+(``bench/reference/<model_type>.py``), each prompt with its served
+tokens.  At every served position the reference gives the logits of the
+next token, and from them the set of tokens the request allows there:
+the best one for a greedy request; for a sampled one the tokens that
+survive its temperature, top-k and top-p filter, as the published
+sampling rule defines it (keep the ``k`` highest tempered logits, then
+the shortest run of them, from the top, whose renormalised mass reaches
 ``p``).  The gap of a served token is how far its logit lies below the
 lowest logit of that set (0 inside it; infinite for a token outside the
 vocabulary).  Two numbers are compared, each the widest gap of its kind:
@@ -32,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .reference import model as ref
+from . import manifest
 
 #: the numbers compared with a limit, by kind of request
 GAP_KEYS = {True: "max_logit_gap", False: "sampled_set_gap"}
@@ -66,11 +67,12 @@ def _allowed_floor(logits, temperature, top_k: int, top_p):
     return jnp.take_along_axis(top, last[:, None], -1)[:, 0] * temperature
 
 
-@functools.partial(jax.jit, static_argnames=("rc", "top_k", "control"))
-def _gaps(weights, tokens, positions, served, temperature, top_p, *, rc,
-          top_k, control):
-    pub = ref.published_layout(weights, rc.head_dim)
-    logits = ref.logits_at(pub, tokens, positions, rc)
+@functools.partial(jax.jit,
+                   static_argnames=("arch", "rc", "top_k", "control"))
+def _gaps(weights, tokens, positions, served, temperature, top_p, *, arch,
+          rc, top_k, control):
+    pub = arch.published_layout(weights, rc)
+    logits = arch.logits_at(pub, tokens, positions, rc)
     floor = _allowed_floor(logits, temperature, top_k, top_p)
 
     def gap(tok):
@@ -81,7 +83,7 @@ def _gaps(weights, tokens, positions, served, temperature, top_p, *, rc,
 
     if control is None:
         return gap(served), gap(served)
-    low = ref.logits_at(pub, tokens, positions, rc, control)
+    low = arch.logits_at(pub, tokens, positions, rc, control)
     return gap(served), gap(jnp.argmax(low, -1))
 
 
@@ -91,7 +93,8 @@ def compare(weights, cfg_file: dict, reqs: list, seq_len: int,
     the control under ``"control"`` when ``control`` is given.  Every
     request is padded to one ``seq_len`` and ``max_out`` so that one
     program serves each kind."""
-    rc = ref.RefConfig.from_file(cfg_file)
+    arch = manifest.architecture(manifest.model_type(cfg_file))
+    rc = arch.RefConfig.from_file(cfg_file)
     widest = {k: 0.0 for k in GAP_KEYS.values()}
     low_widest = dict(widest)
     n_tokens = 0
@@ -109,7 +112,7 @@ def compare(weights, cfg_file: dict, reqs: list, seq_len: int,
         gaps, low = _gaps(weights, jnp.asarray(seq), jnp.asarray(pos),
                           jnp.asarray(served),
                           jnp.float32(s["temperature"]),
-                          jnp.float32(s["top_p"]), rc=rc,
+                          jnp.float32(s["top_p"]), arch=arch, rc=rc,
                           top_k=int(s["top_k"]), control=control)
         key = GAP_KEYS[r.sampling is None]
         widest[key] = max(widest[key], float(np.max(np.asarray(gaps)[:n])))

@@ -4,68 +4,36 @@ These are the numerators of every roofline share and of ``step_mfu``.
 They count what the algorithm needs, not what a kernel happens to do: the
 context a decode row attends to, not the block-padded table it walks; the
 prompt tokens of a prefill chunk, not its padding.  A multiply-add is two
-operations.  ``m`` is a model-shape dict with the keys of
-:func:`model_shape`.
+operations.  ``arch`` is the reference module of the configuration's
+architecture (``bench/reference/<model_type>.py``), which counts one
+token's terms; ``m`` is the model-shape dict of its ``shape(cfg_file)``.
+The kernel counts below need only the keys every such dict has.
 """
 from __future__ import annotations
 
 from typing import Iterable
 
 
-def model_shape(cfg_file: dict) -> dict:
-    """The shapes these counts need, from a benchmark configuration file."""
-    c = cfg_file["config"]
-    heads = c["num_attention_heads"]
-    return {
-        "layers": c["num_hidden_layers"], "d": c["hidden_size"],
-        "heads": heads, "kv_heads": c["num_key_value_heads"],
-        "head_dim": c.get("head_dim") or c["hidden_size"] // heads,
-        "d_ff": c["intermediate_size"], "vocab": c["vocab_size"],
-        "kv_bytes": 2 if c["torch_dtype"] in ("bfloat16", "float16") else 4,
-    }
-
-
-def linear_flops_per_token(m: dict) -> float:
-    """Projections and MLP of every layer, for one token (no attention
-    scores, no embedding, no head)."""
-    d, H, K, D, F = m["d"], m["heads"], m["kv_heads"], m["head_dim"], \
-        m["d_ff"]
-    per_layer = 2 * (d * (H + 2 * K) * D + H * D * d + 3 * d * F)
-    return m["layers"] * per_layer
-
-
-def attention_flops(m: dict, context: int) -> float:
-    """Scores and weighted values of one query over ``context`` keys, all
-    layers."""
-    return m["layers"] * 4 * m["heads"] * m["head_dim"] * context
-
-
-def head_flops(m: dict) -> float:
-    """The tied embedding as the output head, for one logits row."""
-    return 2 * m["d"] * m["vocab"]
-
-
-def decode_step_flops(m: dict, contexts: Iterable[int]) -> float:
+def decode_step_flops(arch, m: dict, contexts: Iterable[int]) -> float:
     """One decode step of the rows that decode, each attending to its
     context (the new token included)."""
     total = 0.0
     for c in contexts:
-        total += linear_flops_per_token(m) + attention_flops(m, c) \
-            + head_flops(m)
+        total += arch.linear_flops_per_token(m) + \
+            arch.attention_flops(m, c) + arch.head_flops(m)
     return total
 
 
-def prefill_flops(m: dict, start: int, stop: int, last: bool) -> float:
-    """Prompt positions ``[start, stop)`` of one request, each attending to
-    every position up to itself; ``last`` adds the logits row that gives
-    the first token."""
+def prefill_flops(arch, m: dict, start: int, stop: int, last: bool) -> float:
+    """Prompt positions ``[start, stop)`` of one request, position ``p``
+    attending to the ``p + 1`` positions up to itself; ``last`` adds the
+    logits row that gives the first token."""
     n = stop - start
     if n <= 0:
         return 0.0
-    # sum over p in [start, stop) of (p + 1) keys
-    keys = (stop * (stop + 1) - start * (start + 1)) // 2
-    total = n * linear_flops_per_token(m) + attention_flops(m, 1) * keys
-    return total + (head_flops(m) if last else 0.0)
+    attn = sum(arch.attention_flops(m, p + 1) for p in range(start, stop))
+    total = n * arch.linear_flops_per_token(m) + attn
+    return total + (arch.head_flops(m) if last else 0.0)
 
 
 def decode_paged_call(m: dict, contexts: Iterable[int]) -> tuple[float, float]:

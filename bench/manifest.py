@@ -8,12 +8,24 @@ metric lives in a file of its own:
     bench/metrics/<metric>.py       the reader of one per-layer metric
     bench/checks/<cell>.json        the correctness limits of one cell
 
-so a cell is added by adding files and entries, never by editing a file.
+and everything that belongs to one architecture, by the published
+``config.model_type`` of its configuration file, in two files:
+
+    bench/reference/<model_type>.py the float32 reference and the counts
+                                    of its work (``reference/common.py``)
+    bench/mapping/<model_type>.py   its published keys onto the program's
+                                    configuration (``bench/system.py``)
+
+so a cell, a configuration or an architecture is added by adding files
+and entries, never by editing a file.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
+import re
+import sys
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parent
@@ -88,13 +100,46 @@ def per_layer(manifest: dict, cell_entry: dict) -> list[dict]:
             if _applies(m, cell_entry, manifest)]
 
 
+def _module(path: Path, what: str):
+    """The module in ``path``, loaded once per process."""
+    if not path.is_file():
+        raise ManifestError(f"{what}: {path} is missing")
+    return _load(str(path))
+
+
+@functools.lru_cache(maxsize=None)
+def _load(path: str):
+    p = Path(path)
+    name = "bench_" + re.sub(r"\W", "_", f"{p.parent.name}_{p.stem}")
+    spec = importlib.util.spec_from_file_location(name, p)
+    module = importlib.util.module_from_spec(spec)
+    # a dataclass looks its module up while it is being made
+    sys.modules[name] = module
+    spec.loader.exec_module(module)
+    return module
+
+
 def metric_reader(name: str, bench: Path = BENCH):
     """The ``read(run) -> float | None`` function of one per-layer metric."""
-    path = Path(bench) / "metrics" / f"{name}.py"
-    if not path.is_file():
-        raise ManifestError(f"per-layer metric {name!r}: {path} is missing")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return _module(Path(bench) / "metrics" / f"{name}.py",
+                   f"per-layer metric {name!r}").read
+
+
+def model_type(cfg_file: dict) -> str:
+    """The published architecture of a configuration file."""
+    return cfg_file["config"]["model_type"]
+
+
+def architecture(name: str, bench: Path = BENCH):
+    """The reference module of the architecture whose ``model_type`` is
+    ``name`` (``bench/reference/<name>.py``)."""
+    return _module(Path(bench) / "reference" / f"{name}.py",
+                   f"model_type {name!r} has no reference")
+
+
+def mapping(name: str, bench: Path = BENCH):
+    """The module that maps the published keys of the architecture whose
+    ``model_type`` is ``name`` onto the program
+    (``bench/mapping/<name>.py``)."""
+    return _module(Path(bench) / "mapping" / f"{name}.py",
+                   f"model_type {name!r} has no mapping")

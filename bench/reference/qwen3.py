@@ -1,10 +1,10 @@
-"""Plain float32 forward pass of a decoder-only GQA transformer.
+"""Qwen3: plain float32 forward pass and the counts of its work.
 
 Written from the published descriptions of Qwen3 (``Qwen3ForCausalLM``:
 pre-norm RMSNorm, GQA, per-head RMSNorm on queries and keys, rotary
 embedding by halves, SwiGLU, tied embedding).  It imports
 nothing of the program under test: no kernels, no cache, no batching, every
-matrix product in float32 at ``HIGHEST`` precision.
+matrix product in float32 at ``HIGHEST`` precision (``common.py``).
 
 Weights come in the tree layout the served program takes (the benchmark
 makes them, see ``bench/weights.py``) and are read here by name, layer by
@@ -12,16 +12,13 @@ layer, each layer cast to float32 inside the scan so that one layer at a
 time is held in float32.
 
 The one layout fact taken over from the served tree is where each rotary
-pair sits: the served tree keeps the two halves of a rotary pair next to
-each other (dims ``2i, 2i+1``), the published layout ``D/2`` apart (dims
-``i, i + D/2``).  :func:`published_layout` permutes the query and key
-columns and their norm scales from the one to the other, the permutation
-a checkpoint converter applies; attention scores do not change under it.
+pair sits (:func:`common.rotary_halves`): :func:`published_layout` permutes
+the query and key columns and their norm scales from the served layout to
+the published one, the permutation a checkpoint converter applies;
+attention scores do not change under it.
 
-``control`` selects the precision step below the configuration's bf16:
-``"fp8"`` rounds both operands of every linear layer (weights per output
-channel, activations per row) to float8 e4m3 with a scale that maps the
-largest magnitude to 448, then multiplies in float32.
+``control="fp8"`` selects the precision step below the configuration's
+bf16 (:func:`common._linear`).
 """
 from __future__ import annotations
 
@@ -29,9 +26,9 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-HIGHEST = jax.lax.Precision.HIGHEST
+from bench.reference.common import (_attention, _linear, _rms, _rope,
+                                    rotary_halves)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,10 +58,9 @@ class RefConfig:
             rms_norm_eps=float(c["rms_norm_eps"]))
 
 
-def published_layout(tree: dict, head_dim: int) -> dict:
+def published_layout(tree: dict, rc: RefConfig) -> dict:
     """Map the served tree to the published names and rotary layout."""
-    perm = np.concatenate([np.arange(0, head_dim, 2),
-                           np.arange(1, head_dim, 2)])
+    perm = rotary_halves(rc.head_dim)
     lay = tree["layers"]
     attn = lay["attn"]
     out = {
@@ -85,67 +81,6 @@ def published_layout(tree: dict, head_dim: int) -> dict:
         },
     }
     return out
-
-
-FP8_MAX = 448.0
-
-
-def _fp8(x: jax.Array, axis) -> jax.Array:
-    """Round to float8 e4m3 with one scale per slice along ``axis``."""
-    amax = jnp.max(jnp.abs(x), axis=axis, keepdims=True)
-    scale = jnp.where(amax > 0, FP8_MAX / amax, 1.0)
-    return (x * scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) / scale
-
-
-def _linear(x: jax.Array, w: jax.Array, spec: str, n_in: int,
-            control: str | None) -> jax.Array:
-    """``einsum(spec, x, w)``; ``n_in`` leading dims of ``w`` are inputs."""
-    if control == "fp8":
-        x = _fp8(x, axis=-1)
-        w = _fp8(w, axis=tuple(range(n_in)))
-    elif control is not None:
-        raise ValueError(f"unknown control precision {control!r}")
-    return jnp.einsum(spec, x, w, precision=HIGHEST)
-
-
-def _rms(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
-
-
-def _rope(x: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding by halves; x: (S, heads, D)."""
-    S, _, D = x.shape
-    inv = 1.0 / theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D)
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[:, None, :]
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[:, None, :]
-    half = D // 2
-    rot = jnp.concatenate([-x[..., half:], x[..., :half]], -1)
-    return x * cos + rot * sin
-
-
-def _attention(q, k, v, q_block: int) -> jax.Array:
-    """Causal GQA attention; q: (S, H, D), k/v: (S, K, D) -> (S, H, D).
-    Queries go in blocks of ``q_block`` rows so that the score block is
-    ``H * q_block * S`` floats."""
-    S, H, D = q.shape
-    K = k.shape[1]
-    G = H // K
-    kpos = jnp.arange(S)
-
-    def block(i):
-        qb = jax.lax.dynamic_slice_in_dim(q, i * q_block, q_block, 0)
-        qb = qb.reshape(q_block, K, G, D)
-        s = jnp.einsum("qkgd,tkd->kgqt", qb, k,
-                       precision=HIGHEST) / np.sqrt(D)
-        qpos = i * q_block + jnp.arange(q_block)
-        s = jnp.where(kpos[None, :] <= qpos[:, None], s, -jnp.inf)
-        w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("kgqt,tkd->qkgd", w, v, precision=HIGHEST)
-        return o.reshape(q_block, H, D)
-
-    out = jax.lax.map(block, jnp.arange(S // q_block))
-    return out.reshape(S, H, D)
 
 
 def hidden_states(weights: dict, tokens: jax.Array, cfg: RefConfig,
@@ -190,3 +125,43 @@ def logits_at(weights: dict, tokens: jax.Array, positions: jax.Array,
     h = hidden_states(weights, tokens, cfg, control)[positions]
     emb = weights["embed"][:cfg.vocab].astype(jnp.float32)
     return _linear(h, emb.T, "sd,dv->sv", 1, control)
+
+
+# ---- counts of the work (``bench/flops.py``); a multiply-add is two ----
+
+def shape(cfg_file: dict) -> dict:
+    """The shapes the counts need, from a benchmark configuration file."""
+    c = cfg_file["config"]
+    heads = c["num_attention_heads"]
+    return {
+        "layers": c["num_hidden_layers"], "d": c["hidden_size"],
+        "heads": heads, "kv_heads": c["num_key_value_heads"],
+        "head_dim": c.get("head_dim") or c["hidden_size"] // heads,
+        "d_ff": c["intermediate_size"], "vocab": c["vocab_size"],
+        "kv_bytes": 2 if c["torch_dtype"] in ("bfloat16", "float16") else 4,
+    }
+
+
+def linear_flops_per_token(m: dict) -> float:
+    """Projections and MLP of every layer, for one token (no attention
+    scores, no embedding, no head)."""
+    d, H, K, D, F = m["d"], m["heads"], m["kv_heads"], m["head_dim"], \
+        m["d_ff"]
+    per_layer = 2 * (d * (H + 2 * K) * D + H * D * d + 3 * d * F)
+    return m["layers"] * per_layer
+
+
+def attention_flops(m: dict, context: int) -> float:
+    """Scores and weighted values of one query over ``context`` keys, all
+    layers (every layer attends to the whole context)."""
+    return m["layers"] * 4 * m["heads"] * m["head_dim"] * context
+
+
+def head_flops(m: dict) -> float:
+    """The tied embedding as the output head, for one logits row."""
+    return 2 * m["d"] * m["vocab"]
+
+
+def paged_decode_layers(m: dict) -> list[tuple[int, int]]:
+    """Every layer calls the paged decode kernel, with all its kv heads."""
+    return [(m["layers"], m["kv_heads"])]

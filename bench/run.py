@@ -5,7 +5,9 @@
 The cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a
 configuration (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<traffic>.json``); its correctness limits are in
-``bench/checks/<cell>.json``.  A run:
+``bench/checks/<cell>.json``; the configuration's ``model_type`` names its
+architecture (``bench/reference/<model_type>.py`` and
+``bench/mapping/<model_type>.py``).  A run:
 
 1. checks for the accelerator: no TPU, a ``device_kind`` missing from
    ``bench/peaks.py`` or fewer chips than the cell asks for exit nonzero
@@ -46,8 +48,8 @@ import numpy as np  # noqa: E402
 ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 
-from bench import (check, flops, loop, manifest, peaks, stats,  # noqa: E402
-                   system, trace_reduce, traffic)
+from bench import (check, loop, manifest, peaks, stats, system,  # noqa: E402
+                   trace_reduce, traffic)
 from bench import weights as W  # noqa: E402
 
 #: the persistent compilation cache, at a fixed path inside the checkout
@@ -83,8 +85,11 @@ def load_cell(name: str) -> dict:
     try:
         man = manifest.load()
         cell = manifest.cell(man, name)
-        return {"manifest": man, "cell": cell,
-                "config": manifest.config(man, cell["config"]),
+        cfg_file = manifest.config(man, cell["config"])
+        model_type = manifest.model_type(cfg_file)
+        manifest.mapping(model_type)    # without it the run is refused
+        return {"manifest": man, "cell": cell, "config": cfg_file,
+                "arch": manifest.architecture(model_type),
                 "traffic": manifest.traffic(cell["traffic"]),
                 "limits": manifest.limits(cell["name"])}
     except (manifest.ManifestError, KeyError, ValueError) as e:
@@ -186,6 +191,7 @@ def run(args, *, require_tpu: bool = True, override=None,
     if override is not None:
         override(ctx)
     cell, cfg_file, mix = ctx["cell"], ctx["config"], ctx["traffic"]
+    arch = ctx["arch"]
     use_program()
     if require_tpu and not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
@@ -193,8 +199,11 @@ def run(args, *, require_tpu: bool = True, override=None,
     devices, peak = accelerator(int(cell["chips"]), require_tpu)
     compiles = CompileCount()
 
-    model = system.build_model(cfg_file)
-    params = W.make(model.abstract(), args.seed, devices[0])
+    try:
+        model = system.build_model(cfg_file)
+    except system.ConfigMismatch as e:
+        raise Refused(2, str(e)) from None
+    params = W.make(model.abstract(), args.seed, devices[0], arch)
     engine = system.build_engine(model, params, cfg_file["engine"])
     if fault is not None:
         fault(engine)
@@ -304,7 +313,7 @@ def run(args, *, require_tpu: bool = True, override=None,
             requests=requests, counted=counted, ticks=ticks,
             counters=(marks["c0"], marks["c1"]),
             compiles_in_window=marks["compiles"], trace=reduced, peak=peak,
-            chips=len(devices), shape=flops.model_shape(cfg_file),
+            chips=len(devices), arch=arch, shape=arch.shape(cfg_file),
             engine=cfg_file["engine"])
         metrics = {}
         for m in manifest.per_layer(man, cell):

@@ -3,24 +3,17 @@
 This is the only module that imports the program (``src/repro``): the
 registered model key, ``Model``, ``ServingEngine`` and its request types.
 The configuration file states the model as it is run, with the published
-key names; every published key maps to a field of the program's
-configuration, so the served model is exactly what the file says.
+key names; its architecture's mapping (``bench/mapping/<model_type>.py``,
+found by ``config.model_type``) turns them into the program's
+configuration and refuses what the program cannot be told, so the served
+model is exactly what the file says.
 """
 from __future__ import annotations
 
 import dataclasses
 
-#: published key -> field of the program's ModelConfig
-PUBLISHED_TO_PROGRAM = {
-    "num_hidden_layers": "n_layers",
-    "hidden_size": "d_model",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "head_dim": "head_dim",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab",
-    "rope_theta": "rope_theta",
-}
+from bench import manifest
+
 #: weight and activation dtypes the served model can take
 DTYPES = ("bfloat16", "float32")
 
@@ -33,28 +26,13 @@ def model_config(cfg_file: dict):
     """The program's ModelConfig for a benchmark configuration file."""
     from repro.configs.base import get_config
     c = cfg_file["config"]
-    fields = {f: c[k] for k, f in PUBLISHED_TO_PROGRAM.items() if k in c}
-    fields["rope_theta"] = float(fields["rope_theta"])
     if c["torch_dtype"] not in DTYPES:
         raise ConfigMismatch(f"torch_dtype {c['torch_dtype']!r} is not one "
                              f"of {DTYPES}")
-    fields["param_dtype"] = fields["dtype"] = c["torch_dtype"]
-    fields["qk_norm"] = c["model_type"] == "qwen3"
-    mc = dataclasses.replace(get_config(cfg_file["model"]), **fields)
-    # what the program cannot be told, it must already do
-    if not c.get("tie_word_embeddings", False):
-        raise ConfigMismatch("the served model ties its embedding and head; "
-                             "an untied configuration cannot be run")
-    if c["hidden_act"] != "silu" or c.get("attention_bias", False) \
-            or c.get("mlp_bias", False):
-        raise ConfigMismatch("the served dense family is SwiGLU without "
-                             "attention or MLP biases")
-    if float(c["rms_norm_eps"]) != 1e-6:
-        raise ConfigMismatch("the served RMSNorm has epsilon 1e-6")
-    if mc.family != "dense" or mc.sliding_window or mc.layer_pattern:
-        raise ConfigMismatch(f"{cfg_file['model']} is not a dense "
-                             "full-attention model")
-    return mc
+    mapping = manifest.mapping(manifest.model_type(cfg_file))
+    mc = mapping.program_config(c, get_config(cfg_file["model"]))
+    return dataclasses.replace(mc, param_dtype=c["torch_dtype"],
+                               dtype=c["torch_dtype"])
 
 
 def build_model(cfg_file: dict):

@@ -14,6 +14,14 @@ def test_every_cell_finds_its_files_by_name(cell):
     c = manifest.cell(MAN, cell)
     cfg = manifest.config(MAN, c["config"])
     assert cfg["name"] == c["config"]
+    arch = manifest.architecture(manifest.model_type(cfg))
+    for fn in ("published_layout", "logits_at", "shape",
+               "linear_flops_per_token", "attention_flops", "head_flops",
+               "paged_decode_layers"):
+        assert callable(getattr(arch, fn)), fn
+    hash(arch.RefConfig.from_file(cfg))     # a static argument of the check
+    assert callable(manifest.mapping(manifest.model_type(cfg))
+                    .program_config)
     assert manifest.traffic(c["traffic"])["kind"]
     lim = manifest.limits(cell)
     assert lim["max_logit_gap"] > 0 and lim["min_tokens"] > 0

@@ -1,10 +1,14 @@
 """``bench/run.py`` measures only on a TPU it knows: elsewhere it exits
-nonzero and prints no result."""
+nonzero and prints no result; so does a configuration whose
+``model_type`` has no reference or no mapping file."""
+import json
 import os
 import shutil
 import subprocess
 import sys
 from pathlib import Path
+
+import pytest
 
 ROOT = Path(__file__).resolve().parents[2]
 ARGS = ["--workload", "qwen3-chat", "--seed", str(2**31 + 9),
@@ -41,3 +45,22 @@ def test_unknown_cell_exits_nonzero():
                         "--seconds", "1"], cwd=ROOT, env=env,
                        capture_output=True, text=True, timeout=300)
     assert p.returncode == 2 and p.stdout.strip() == ""
+
+
+@pytest.mark.parametrize("missing", ["both", "reference", "mapping"])
+def test_model_type_without_its_files_exits_2(tmp_path, missing):
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (tmp_path / "src").symlink_to(ROOT / "src")
+    cfg_path = tmp_path / "bench" / "configs" / "qwen3-1.7b.json"
+    cfg = json.loads(cfg_path.read_text())
+    if missing == "both":
+        cfg["config"]["model_type"] = "no_such_architecture"
+        cfg_path.write_text(json.dumps(cfg))
+    else:
+        (tmp_path / "bench" / missing / "qwen3.py").unlink()
+    p = _run(tmp_path, tmp_path / "bench" / "run.py")
+    assert p.returncode == 2, p.stderr[-2000:]
+    assert p.stdout.strip() == ""
+    assert "model_type" in p.stderr

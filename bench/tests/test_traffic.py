@@ -45,6 +45,21 @@ def test_seeds_share_the_work_in_another_order():
     assert len(ga & gb) >= len(ga) - 1
 
 
+def test_fixed_order_varies_only_the_tokens():
+    mix = _open_mix()
+    mix["fixed_order"] = True
+    a, b = (Traffic(mix, seed, 20, 1000).requests for seed in (1, 2))
+    assert [(r.phase, r.due, len(r.prompt), r.max_new, r.greedy)
+            for r in a] == [(r.phase, r.due, len(r.prompt), r.max_new,
+                             r.greedy) for r in b]
+    assert [r.prompt.tobytes() for r in a] != [r.prompt.tobytes() for r in b]
+    free = [r for r in Traffic(_open_mix(), 1, 20, 1000).requests
+            if r.phase == "window"]
+    fixed = [r for r in a if r.phase == "window"]
+    assert sorted((len(r.prompt), r.max_new) for r in fixed) == \
+        sorted((len(r.prompt), r.max_new) for r in free)
+
+
 def test_window_holds_rate_times_seconds():
     t = Traffic(_open_mix(), 5, 20, 1000)
     w = [r for r in t.requests if 0.0 <= r.due < 20]

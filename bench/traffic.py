@@ -6,7 +6,10 @@ length distributions.  The *sizes* of the work come from the mix's own
 output lengths, greedy/sampled requests and inter-arrival gaps, in another
 order.  The run seed chooses that order and every token.  So two seeds
 offer the same work, and the spread between runs is the system's, not the
-generator's.
+generator's.  With ``fixed_order`` the order is the mix's own too, and the
+run seed chooses only the tokens and the sampling seeds: where requests
+live about as long as the window, any reorder moves work across its
+edges.
 
 Kind ``open_poisson``: independent users.  Requests are due on a schedule
 that does not wait for the server: exponential gaps at ``rate_rps``.  The
@@ -126,6 +129,7 @@ class Traffic:
                   ("drain", float(mix["drain_cap_s"])))
         reqs: list[Req] = []
         t = -phases[0][1]
+        fixed = bool(mix.get("fixed_order", False))
         n_prime = int(mix.get("prime", 0))
         if n_prime:
             shapes = _shapes(mix, n_prime, self._shape_rng)
@@ -140,8 +144,11 @@ class Traffic:
                 continue
             shapes = _shapes(mix, n, self._shape_rng)
             gaps = _gaps(rate, n, length, self._shape_rng)
-            order = self._rng.permutation(n)
-            gaps = gaps[self._rng.permutation(n)]
+            if not fixed:
+                order = self._rng.permutation(n)
+                gaps = gaps[self._rng.permutation(n)]
+            else:
+                order = np.arange(n)
             # the first request of a phase is due at its start, so the
             # window holds exactly n requests due in [0, seconds)
             dues = t + np.concatenate([[0.0], np.cumsum(gaps)[:-1]])

@@ -8,7 +8,10 @@ dtype it is served in:
 * RMSNorm scales: ``1 + 0.1 * N(0, 1)``, so that a norm scale that the
   served path dropped would show in the comparison with the reference;
 * the embedding: ``0.02 * N(0, 1)``;
-* every projection: ``N(0, 1) / sqrt(fan_in)``.
+* every projection: ``N(0, 1) / sqrt(fan_in)``, the fan-in as the
+  architecture's ``fan_in(leaf_name, shape)`` gives it
+  (``bench/reference/<model_type>.py``), or by :func:`default_fan_in`
+  where it defines none.
 """
 from __future__ import annotations
 
@@ -26,8 +29,8 @@ def _leaf_name(path) -> str:
     return str(getattr(path[-1], "key", path[-1]))
 
 
-def _fan_in(name: str, shape: tuple) -> int:
-    # stacked layer leaves: (layers, in..., out...)
+def default_fan_in(name: str, shape: tuple) -> int:
+    """Stacked layer leaves: (layers, in..., out...)."""
     if name in _TWO_INPUT_DIMS:
         return shape[1] * shape[2]
     return shape[1]
@@ -40,7 +43,7 @@ def run_key(seed: int) -> jax.Array:
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
-def _draw(abstract, key):
+def _draw(abstract, fan_in, key):
     leaves, treedef = jax.tree_util.tree_flatten_with_path(abstract)
     keys = jax.random.split(key, len(leaves))
     out = []
@@ -52,17 +55,20 @@ def _draw(abstract, key):
         elif name == "tokens":
             x = 0.02 * z
         else:
-            x = z / math.sqrt(_fan_in(name, leaf.shape))
+            x = z / math.sqrt(fan_in(name, leaf.shape))
         out.append(x.astype(leaf.dtype))
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
-def make(abstract, seed: int, device=None):
+def make(abstract, seed: int, device=None, arch=None):
     """The weight tree for ``abstract`` (a tree of ShapeDtypeStruct) from
     ``seed``, in one jitted program on ``device`` (default device if
-    None)."""
-    fn = jax.jit(functools.partial(_draw, abstract))
+    None), with the fan-in rule of ``arch`` (the architecture's reference
+    module) where it has one."""
+    fan_in = getattr(arch, "fan_in", default_fan_in)
+    draw = functools.partial(_draw, abstract, fan_in)
+    fn = jax.jit(draw)
     if device is not None:
-        fn = jax.jit(functools.partial(_draw, abstract),
+        fn = jax.jit(draw,
                      out_shardings=jax.sharding.SingleDeviceSharding(device))
     return jax.block_until_ready(fn(run_key(seed)))
