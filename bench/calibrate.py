@@ -34,9 +34,10 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--fault", choices=sorted(faults.FAULTS))
+    every = {**faults.FAULTS, **faults.STATE_SPACE_FAULTS}
+    ap.add_argument("--fault", choices=sorted(every))
     a = ap.parse_args(argv)
-    fault = faults.FAULTS[a.fault] if a.fault else None
+    fault = every[a.fault] if a.fault else None
     for seed in (int(s) for s in a.seeds.split(",")):
         args = argparse.Namespace(workload=a.workload, seed=seed,
                                   seconds=a.seconds, trace=0)

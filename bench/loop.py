@@ -6,6 +6,9 @@ around the three things the thread does (``bench.submit``,
 ``bench.engine_step``, ``bench.gen_sleep``) go into the profiler's trace
 when one is running.
 
+After each tick it copies every live request's ``queued_s``, the time
+the program's scheduler kept it waiting for a slot.
+
 The loop also keeps, per engine tick, the work the scheduler planned:
 the context of every decoding row and the prompt range of every prefill
 chunk.  It reads them from the tick plan the scheduler hands the engine,
@@ -94,6 +97,7 @@ class Loop:
             n = len(preq.generated)
             while len(req.token_times) < n:
                 req.token_times.append(now)
+            req.queued_s = preq.queued_s
             if preq.done:
                 req.done = True
                 req.tokens = list(preq.generated)

@@ -17,7 +17,19 @@ and everything that belongs to one architecture, by the published
                                     configuration (``bench/system.py``)
 
 so a cell, a configuration or an architecture is added by adding files
-and entries, never by editing a file.
+and entries, never by editing a file.  An architecture's reference module
+may also draw its own weight leaves (``draw``, the interface in
+``reference/common.py``), as a state-space layer's decay and step leaves
+must be drawn.
+
+A metric's reader, ``read(run)``, gets the run's record
+(``bench/run.py``): besides the requests, the tick plans and the reduced
+trace, the program's own spans in the trace (``serving.step`` and its
+stages, ``*.wait`` where the host blocked on the device: ``trace.spans``),
+each request's ``queued_s`` (its wait for a slot), and ``engine_stats``,
+every number of the engine's ``stats()`` at the window's open and close,
+flattened to dotted keys (``kv_pool.leased_block_ticks``).  So a metric
+of a new mechanism's spans or counters is one new reader file.
 """
 from __future__ import annotations
 

@@ -27,7 +27,17 @@ nothing of the program and exposes:
   with how many kv heads;
 * optionally ``fan_in(leaf_name, shape)``: the fan-in of a stacked weight
   leaf for ``bench/weights.py``, which otherwise takes ``shape[1]``
-  (``shape[1] * shape[2]`` for ``wo``).
+  (``shape[1] * shape[2]`` for ``wo``);
+* optionally ``draw(leaf_name, shape, key)``: the leaf drawn as the
+  architecture publishes its initialisation, a float32 array of
+  ``shape`` from the leaf's own PRNG ``key``, or None to leave the leaf
+  to ``bench/weights.py``'s rule.  It is traced inside the one jitted
+  draw, so it uses ``jax.random`` and ``jax.numpy`` only.  A state-space
+  or gated linear-attention architecture must draw its decay and step
+  leaves as published (Mamba2: ``A`` uniform in [1, 16], the step
+  log-uniform in [1e-3, 1e-1]): under the generic rule every head forgets
+  within a token or two, and a state lost between prefill chunks cannot
+  show in the served tokens the check compares.
 
 Every matrix product here is float32 at ``HIGHEST`` precision.
 """

@@ -86,10 +86,8 @@ def load_cell(name: str) -> dict:
         man = manifest.load()
         cell = manifest.cell(man, name)
         cfg_file = manifest.config(man, cell["config"])
-        model_type = manifest.model_type(cfg_file)
-        manifest.mapping(model_type)    # without it the run is refused
         return {"manifest": man, "cell": cell, "config": cfg_file,
-                "arch": manifest.architecture(model_type),
+                "arch": manifest.architecture(manifest.model_type(cfg_file)),
                 "traffic": manifest.traffic(cell["traffic"]),
                 "limits": manifest.limits(cell["name"])}
     except (manifest.ManifestError, KeyError, ValueError) as e:
@@ -163,11 +161,21 @@ def warm_shapes(engine, vocab: int, chunk: int) -> None:
     engine.scheduler.cfg.chunk = chunk
 
 
-def counters(engine) -> dict:
-    s = engine.stats()
-    return {"tokens_out": s["tokens_out"],
-            "prefill_tokens": s["prefill_tokens"],
-            "prefill_tokens_saved": s.get("prefill_tokens_saved", 0)}
+def flat_stats(engine) -> dict:
+    """Every number of the engine's ``stats()``, the keys of nested groups
+    joined by dots (``kv_pool.leased_block_ticks``, ``stages.step.calls``).
+    Booleans and strings are left out."""
+    out = {}
+
+    def walk(prefix: str, value) -> None:
+        if isinstance(value, dict):
+            for k, v in value.items():
+                walk(f"{prefix}{k}.", v)
+        elif isinstance(value, (int, float)) and not isinstance(value, bool):
+            out[prefix[:-1]] = value
+
+    walk("", engine.stats())
+    return out
 
 
 def seq_bound(mix: dict) -> tuple[int, int]:
@@ -185,24 +193,33 @@ def run(args, *, require_tpu: bool = True, override=None,
     size the CPU holds, and a ``fault(engine)`` that breaks the timed path
     underneath; ``bench/calibrate.py`` passes ``control`` to judge the
     control beside the program (and a ``fault``), and ``bench/sweep.py`` a
-    ``keep`` dict that receives the run's requests and ticks.  A benchmark
-    run passes none."""
+    ``keep`` dict that receives the run's requests, ticks and engine
+    statistics.  A benchmark run passes none.
+
+    A per-layer metric's reader gets the run's record: the cell, the
+    window and the traced span of it, the requests (each with its
+    ``queued_s``, the program's time waiting for a slot) and those due in
+    the window, the tick plans, ``engine_stats`` (the engine's numbers
+    at the window's open and close, :func:`flat_stats`),
+    the reduced trace with the harness's ``bench.*`` and the program's
+    ``serving.*`` spans, the peaks, the architecture and its shape."""
     ctx = load_cell(args.workload)
     if override is not None:
         override(ctx)
     cell, cfg_file, mix = ctx["cell"], ctx["config"], ctx["traffic"]
     arch = ctx["arch"]
     use_program()
+    try:
+        # the architecture's mapping is looked up here, and only here
+        model = system.build_model(cfg_file)
+    except (system.ConfigMismatch, manifest.ManifestError) as e:
+        raise Refused(2, str(e)) from None
     if require_tpu and not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     devices, peak = accelerator(int(cell["chips"]), require_tpu)
     compiles = CompileCount()
 
-    try:
-        model = system.build_model(cfg_file)
-    except system.ConfigMismatch as e:
-        raise Refused(2, str(e)) from None
     params = W.make(model.abstract(), args.seed, devices[0], arch)
     engine = system.build_engine(model, params, cfg_file["engine"])
     if fault is not None:
@@ -221,7 +238,7 @@ def run(args, *, require_tpu: bool = True, override=None,
     def on_open():
         marks["open"] = time.perf_counter()
         marks["compiles"] = compiles.n
-        marks["c0"] = counters(engine)
+        marks["s0"] = flat_stats(engine)
         if trace_dir:
             # a span's clock starts when it is made, so it is made here
             marks["span"] = jax.profiler.TraceAnnotation("bench.window")
@@ -234,7 +251,7 @@ def run(args, *, require_tpu: bool = True, override=None,
             marks["span"].__exit__(None, None, None)
         marks["close"] = time.perf_counter()
         marks["compiles"] = compiles.n - marks["compiles"]
-        marks["c1"] = counters(engine)
+        marks["s1"] = flat_stats(engine)
 
     if trace_dir:
         # the trace opens with the warm phase, so that its own start-up
@@ -254,7 +271,7 @@ def run(args, *, require_tpu: bool = True, override=None,
     setup_s = marks["open"] - T_START
     if keep is not None:
         keep.update(requests=requests, counted=counted, window=window,
-                    ticks=lp.ticks)
+                    ticks=lp.ticks, engine_stats=(marks["s0"], marks["s1"]))
     in_window = [t for t in lp.ticks if t0 <= t.t1 <= window[1]]
     log(f"{len(in_window)} engine ticks in the window, mean "
         f"{1e3 * sum(t.t1 - t.t0 for t in in_window) / max(len(in_window), 1):.1f}"
@@ -311,7 +328,7 @@ def run(args, *, require_tpu: bool = True, override=None,
             cell=cell, window=window, window_s=args.seconds,
             traced=(marks["open"], marks["close"]),
             requests=requests, counted=counted, ticks=ticks,
-            counters=(marks["c0"], marks["c1"]),
+            engine_stats=(marks["s0"], marks["s1"]),
             compiles_in_window=marks["compiles"], trace=reduced, peak=peak,
             chips=len(devices), arch=arch, shape=arch.shape(cfg_file),
             engine=cfg_file["engine"])

@@ -1,5 +1,6 @@
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 from bench import trace_reduce as T
@@ -74,3 +75,34 @@ def test_window_span_clips_the_trace():
     assert tr.busy_s == pytest.approx(0.5 + 0.4)
     assert tr.ops("fusion") == (2, pytest.approx(0.9))
     assert [g for g, _ in tr.idle_gaps()] == pytest.approx([0.3, 0.3])
+
+
+def test_gap_names_are_the_shortest_open_span():
+    """The walk back over nested spans names what a search of every span
+    for the shortest one open at the time would name."""
+    rng = np.random.default_rng(5)
+    ev, t = [], 0.0
+    for tick in range(200):
+        length = rng.uniform(0.05, 0.3)
+        ev.append(T.Event("span", -1, "bench.engine_step", t, length))
+        ev.append(T.Event("span", -1, "serving.step", t + 0.001,
+                          length - 0.002))
+        a = t + 0.001
+        for stage in ("plan", "prefill_chunk", "decode"):
+            d = rng.uniform(0.0, (t + length - 0.001 - a) / 2)
+            ev.append(T.Event("span", -1, f"serving.{stage}", a, d))
+            if d > 0.01:
+                ev.append(T.Event("span", -1, f"serving.{stage}.wait",
+                                  a + d / 2, d / 3))
+            a += d
+        t += length + rng.uniform(0.0, 0.02)
+        ev.append(T.Event("span", -1, "bench.gen_sleep", t, 0.005))
+        t += 0.01
+    ev.append(T.Event("op", 0, "fusion", 0.0, t))
+    tr = T.Trace(ev)
+    spans = [e for e in ev if e.kind == "span"]
+    for x in rng.uniform(0.0, t, 2000):
+        open_ = [s for s in spans if s.start <= x <= s.start + s.dur]
+        want = min(open_, key=lambda s: s.dur).name if open_ \
+            else "outside the benchmark's spans"
+        assert tr.span_at(x) == want, x

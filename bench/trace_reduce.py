@@ -5,8 +5,9 @@ planes (``/device:TPU:<n>``) hold a line of XLA programs (``XLA Modules``,
 one event per executed program) and a line of the operations inside them
 (``XLA Ops``, one event per executed HLO instruction, the Pallas kernels
 among them as custom calls).  The host plane holds the benchmark's own
-spans (``bench.submit``, ``bench.engine_step``, ``bench.gen_sleep``) on
-the same clock.
+spans (``bench.submit``, ``bench.engine_step``, ``bench.gen_sleep``) and
+the program's (``serving.step`` and its stages, ``serving.decode.wait``
+and the like: ``repro.serving.engine``) on the same clock.
 
 :func:`events_from_xplane` turns a trace into plain event tuples, and
 :class:`Trace` reduces them: busy time as the union of operation
@@ -21,12 +22,14 @@ import bisect
 import dataclasses
 import glob
 import gzip
+import itertools
 import json
 import os
 import re
 
-#: host spans the load loop opens (bench/loop.py)
-SPAN_PREFIX = "bench."
+#: host spans kept: the load loop's (bench/loop.py) and the serving
+#: engine's stages
+SPAN_PREFIX = ("bench.", "serving.")
 #: the span ``bench/run.py`` holds open for the measured window
 WINDOW_SPAN = "bench.window"
 _MODULE_RE = re.compile(r"^(.*?)(\(\d+\))?$")
@@ -144,8 +147,12 @@ class Trace:
             busy.append(sum(b - a for a, b in u))
         #: seconds in which an operation ran, averaged over the chips
         self.busy_s = sum(busy) / n_devices
-        self._spans = sorted(spans, key=lambda e: e.start)
+        # at one start the longer span is the outer one, so it sorts first
+        self._spans = sorted(spans, key=lambda e: (e.start, -e.dur))
         self._span_starts = [s.start for s in self._spans]
+        #: the latest end of the spans up to each one
+        self._ends_so_far = list(itertools.accumulate(
+            (s.start + s.dur for s in self._spans), max))
 
     # -- time by name -----------------------------------------------------------
     def _sum(self, kind: str, match) -> tuple[int, float]:
@@ -165,18 +172,23 @@ class Trace:
         """(calls, device seconds) of the programs named ``name``."""
         return self._sum("module", lambda n: n == name)
 
+    def spans(self, match) -> list[Event]:
+        """The host spans whose name ``match(name)`` accepts, by start."""
+        return [s for s in self._spans if match(s.name)]
+
     # -- idle gaps --------------------------------------------------------------
     def span_at(self, t: float) -> str:
-        """The innermost benchmark span open at ``t`` on the host."""
-        best = None
-        i = bisect.bisect_right(self._span_starts, t)
-        for s in reversed(self._spans[:i]):
-            if s.start <= t <= s.start + s.dur:
-                if best is None or s.dur < best.dur:
-                    best = s
-            if best is not None and s.start + 600.0 < t:
+        """The innermost span open at ``t`` on the host.  The host's spans
+        nest, so that is the one opened last of those still open at
+        ``t``; the walk back stops where no earlier span reaches ``t``."""
+        for k in range(bisect.bisect_right(self._span_starts, t) - 1, -1,
+                       -1):
+            if self._ends_so_far[k] < t:
                 break
-        return best.name if best else "outside the benchmark's spans"
+            s = self._spans[k]
+            if t <= s.start + s.dur:
+                return s.name
+        return "outside the benchmark's spans"
 
     def idle_gaps(self, chip: int = 0) -> list[tuple[float, str]]:
         """Every idle gap of ``chip`` inside the window, longest first,

@@ -49,6 +49,7 @@ class Req:
     tokens: list | None = None
     failed: bool = False
     done: bool = False
+    queued_s: float | None = None   # the program's wait for a slot
 
 
 def lengths(spec: dict, n: int, rng: np.random.Generator) -> np.ndarray:

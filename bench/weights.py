@@ -11,7 +11,12 @@ dtype it is served in:
 * every projection: ``N(0, 1) / sqrt(fan_in)``, the fan-in as the
   architecture's ``fan_in(leaf_name, shape)`` gives it
   (``bench/reference/<model_type>.py``), or by :func:`default_fan_in`
-  where it defines none.
+  where it defines none;
+* except a leaf for which the architecture's optional
+  ``draw(leaf_name, shape, key)`` returns an array: that array, as the
+  architecture publishes its initialisation (a state-space layer's decay
+  and step leaves, whose scale sets how long its state remembers).  It is
+  drawn inside the same jitted call, from the leaf's own key.
 """
 from __future__ import annotations
 
@@ -43,12 +48,16 @@ def run_key(seed: int) -> jax.Array:
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
-def _draw(abstract, fan_in, key):
+def _draw(abstract, fan_in, own, key):
     leaves, treedef = jax.tree_util.tree_flatten_with_path(abstract)
     keys = jax.random.split(key, len(leaves))
     out = []
     for (path, leaf), k in zip(leaves, keys):
         name = _leaf_name(path)
+        x = own(name, leaf.shape, k)
+        if x is not None:
+            out.append(jnp.asarray(x).astype(leaf.dtype))
+            continue
         z = jax.random.normal(k, leaf.shape, jnp.float32)
         if "norm" in name:
             x = 1.0 + 0.1 * z
@@ -63,10 +72,11 @@ def _draw(abstract, fan_in, key):
 def make(abstract, seed: int, device=None, arch=None):
     """The weight tree for ``abstract`` (a tree of ShapeDtypeStruct) from
     ``seed``, in one jitted program on ``device`` (default device if
-    None), with the fan-in rule of ``arch`` (the architecture's reference
-    module) where it has one."""
+    None), with the fan-in rule and the draws of ``arch`` (the
+    architecture's reference module) where it has them."""
     fan_in = getattr(arch, "fan_in", default_fan_in)
-    draw = functools.partial(_draw, abstract, fan_in)
+    own = getattr(arch, "draw", lambda name, shape, key: None)
+    draw = functools.partial(_draw, abstract, fan_in, own)
     fn = jax.jit(draw)
     if device is not None:
         fn = jax.jit(draw,
